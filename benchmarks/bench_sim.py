@@ -45,7 +45,6 @@ from repro.core.cluster import WorkloadProfile
 from repro.sim import (Fabric, append_bench_run, compare_allocators,
                        compare_backends, compare_engine_variants,
                        compare_policies, cross_validate_bigquery,
-                       jit_available,
                        lovelock_cluster, measure_interference,
                        multi_tenant, perf_digest,
                        pipeline_bubble_report,
@@ -488,7 +487,6 @@ def scenario_engine_xscale(smoke=False):
     jcmp.pop("results")
     out["n_events"] += jcmp["numpy"]["n_events"] + jcmp["jit"]["n_events"]
     out["jit"] = {
-        "active": jit_available(),
         "waves": 2,
         "n_tasks": len(tasks_of(make_topo(), 2)),
         "bit_identical": jcmp["bit_identical"]["jit"],

@@ -2,9 +2,11 @@
 
 Prints ``name,us_per_call,derived`` CSV.  Usage:
     PYTHONPATH=src python -m benchmarks.run [--full]
+
+The collective-traffic bench needs 8 fake CPU devices and so a process of
+its own: ``python -m benchmarks.bench_collectives``.
 """
 import argparse
-import sys
 
 
 def main() -> None:
@@ -14,23 +16,15 @@ def main() -> None:
                          "sizes")
     args = ap.parse_args()
 
-    from benchmarks import (bench_collectives, bench_costmodel, bench_fig3,
-                            bench_fig4, bench_kernels, bench_table1,
-                            bench_table2, roofline)
+    from benchmarks import (bench_costmodel, bench_fig3, bench_fig4,
+                            bench_kernels, bench_table1, bench_table2,
+                            roofline)
     print("name,us_per_call,derived")
     mods = [bench_costmodel, bench_table1, bench_fig3, bench_fig4,
-            bench_table2, bench_collectives, bench_kernels, roofline]
-    failed = 0
+            bench_table2, bench_kernels, roofline]
     for mod in mods:
-        try:
-            for name, us, derived in mod.run():
-                print(f"{name},{us:.1f},{derived}")
-        except Exception as e:  # noqa: BLE001
-            failed += 1
-            print(f"{mod.__name__},0,ERROR {type(e).__name__}: {e}",
-                  file=sys.stderr)
-    if failed:
-        sys.exit(1)
+        for name, us, derived in mod.run():
+            print(f"{name},{us:.1f},{derived}")
 
 
 if __name__ == '__main__':

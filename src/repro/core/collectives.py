@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 Pytree = Any
 
 
@@ -81,11 +79,10 @@ def flat_all_reduce(x, mesh, axes=("pod", "data")):
 
     def f(x):
         return lax.psum(x, axes)
-    # fully manual (not axis_names=axes): partial-manual mode aborts XLA's
-    # SPMD partitioner on jax 0.4.x, and the unused model axis simply
-    # replicates under manual mode with identical semantics
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=P(axes),
-                             out_specs=P()))(x)
+    # fully manual: the unused model axis simply replicates, with the same
+    # semantics as manual over `axes` alone
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(axes),
+                                 out_specs=P(), check_vma=False))(x)
 
 
 def hierarchical_all_reduce(x, mesh):
@@ -103,8 +100,8 @@ def hierarchical_all_reduce(x, mesh):
         if "pod" in axes:
             shard = lax.psum(shard, "pod")
         return lax.all_gather(shard, "data", axis=0, tiled=True)[None]
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=P(axes),
-                             out_specs=P()))(x)
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(axes),
+                                 out_specs=P(), check_vma=False))(x)
 
 
 # ---------------------------------------------------------------------------

@@ -32,10 +32,10 @@ def _kernel(q_ref, k_ref, v_ref, b_ref, o_ref, m_scr, l_scr, acc_scr, *,
     q = q_ref[0, 0].astype(jnp.float32)                 # (G, d)
     k = k_ref[0, 0].astype(jnp.float32)                 # (bw, d)
     v = v_ref[0, 0].astype(jnp.float32)
-    bias = b_ref[0].astype(jnp.float32)                 # (bw,)
+    bias = b_ref[0].astype(jnp.float32)                 # (1, bw)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    s = s + bias[None, :]                               # (G, bw)
+    s = s + bias                                        # (G, bw)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.exp(m_prev - m_new)
@@ -54,7 +54,12 @@ def _kernel(q_ref, k_ref, v_ref, b_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def decode_attention_fwd(q, k, v, bias, *, bw=DEFAULT_BW, scale=None,
                          interpret=False):
-    """q (B,K,G,d), k/v (B,K,W,d), bias (B,W) — W % bw == 0."""
+    """q (B,K,G,d), k/v (B,K,W,d), bias (B,1,W) — W % bw == 0.
+
+    The bias keeps a unit middle axis so that its (1, 1, bw) block spans the
+    array's last two dims in full or in 128-lane multiples, as Mosaic
+    requires for every batch size.
+    """
     B, K, G, d = q.shape
     W = k.shape[2]
     assert W % bw == 0, (W, bw)
@@ -68,7 +73,7 @@ def decode_attention_fwd(q, k, v, bias, *, bw=DEFAULT_BW, scale=None,
             pl.BlockSpec((1, 1, G, d), lambda b, kk, iw: (b, kk, 0, 0)),
             pl.BlockSpec((1, 1, bw, d), lambda b, kk, iw: (b, kk, iw, 0)),
             pl.BlockSpec((1, 1, bw, d), lambda b, kk, iw: (b, kk, iw, 0)),
-            pl.BlockSpec((1, bw), lambda b, kk, iw: (b, iw)),
+            pl.BlockSpec((1, 1, bw), lambda b, kk, iw: (b, 0, iw)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, d), lambda b, kk, iw: (b, kk, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, G, d), q.dtype),

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import interpret_mode
 from repro.kernels.decode_attention.decode_attention import (
     DEFAULT_BW, decode_attention_fwd)
 
@@ -15,8 +16,11 @@ def _ceil_to(x, m):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_attention(q, k, v, bias, *, interpret=True):
-    """q (B,1,H,d), k/v (B,W,K,d), bias (B,W) -> (B,1,H,d)."""
+def decode_attention(q, k, v, bias, *, interpret=None):
+    """q (B,1,H,d), k/v (B,W,K,d), bias (B,W) -> (B,1,H,d).
+
+    interpret=None: interpreted on the CPU backend, compiled elsewhere.
+    """
     B, _, H, d = q.shape
     W, K = k.shape[1], k.shape[2]
     G = H // K
@@ -29,7 +33,9 @@ def decode_attention(q, k, v, bias, *, interpret=True):
                  ((0, 0), (0, 0), (0, Wp - W), (0, dp - d)))
     vt = jnp.pad(v.transpose(0, 2, 1, 3),
                  ((0, 0), (0, 0), (0, Wp - W), (0, dp - d)))
-    bp = jnp.pad(bias, ((0, 0), (0, Wp - W)), constant_values=-1e30)
+    bp = jnp.pad(bias, ((0, 0), (0, Wp - W)),
+                 constant_values=-1e30)[:, None, :]              # (B,1,Wp)
     o = decode_attention_fwd(qt, kt, vt, bp, bw=bw,
-                             scale=1.0 / (d ** 0.5), interpret=interpret)
+                             scale=1.0 / (d ** 0.5),
+                             interpret=interpret_mode(interpret))
     return o[..., :d].reshape(B, 1, H, d)

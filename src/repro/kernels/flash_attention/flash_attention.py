@@ -1,6 +1,6 @@
 """Flash attention forward — Pallas TPU kernel.
 
-TPU adaptation (DESIGN.md §2): block-tiled online softmax with explicit
+TPU adaptation: block-tiled online softmax with explicit
 VMEM BlockSpecs.  Grid = (B, H, n_q_blocks, n_k_blocks); the k-block axis
 is innermost, so VMEM scratch accumulators (m, l, acc) persist across it
 (TPU grids iterate sequentially).  GQA is handled in the k/v index_map

@@ -1,9 +1,8 @@
 """jit'd wrapper: padding, dtype handling, custom_vjp.
 
-Forward runs the Pallas kernel (TPU) or the jnp oracle (CPU / interpret
-off); backward always recomputes through the oracle (fwd-only kernel —
-the backward flash kernel is an optimization left on the table and noted
-in EXPERIMENTS.md §Perf).
+Forward runs the Pallas kernel: compiled on a TPU, interpreted on the CPU.
+Backward recomputes through the jnp oracle (the kernel is forward-only;
+a backward flash kernel is still to be written).
 """
 from __future__ import annotations
 
@@ -12,6 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import interpret_mode
 from repro.kernels.flash_attention.flash_attention import (
     DEFAULT_BK, DEFAULT_BQ, flash_attention_fwd)
 from repro.kernels.flash_attention.ref import flash_attention_ref
@@ -60,10 +60,10 @@ _flash.defvjp(_fwd, _bwd)
 
 @functools.partial(jax.jit, static_argnames=("causal", "window",
                                              "interpret"))
-def flash_attention(q, k, v, *, causal=True, window=None, interpret=True):
+def flash_attention(q, k, v, *, causal=True, window=None, interpret=None):
     """Drop-in attention core: q (B,S,H,d), k/v (B,S,K,d) -> (B,S,H,d).
 
-    interpret=True (default) executes the kernel body in Python on CPU —
-    correct everywhere; set False on real TPUs.
+    interpret=None interprets the kernel on the CPU backend and compiles it
+    everywhere else (`repro.kernels.backend.interpret_mode`).
     """
-    return _flash(q, k, v, causal, window, interpret)
+    return _flash(q, k, v, causal, window, interpret_mode(interpret))

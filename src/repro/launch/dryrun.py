@@ -1,11 +1,14 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("_DRYRUN_EXTRA_XLA", "") +
                            " --xla_force_host_platform_device_count=512")
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST precede any jax import (jax locks the device count
-at first init).  Usage:
+The lines above MUST precede any jax import (jax locks the platform and
+the device count at first init).  The dry-run compiles for 512 fake host
+devices on the CPU, so it never takes an accelerator, even where one is
+attached.  Usage:
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-32b \
         --shape train_4k --mesh single

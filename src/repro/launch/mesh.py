@@ -4,41 +4,28 @@
 this module never touches jax device state.  The single-pod production mesh
 is 16x16 = 256 chips (one TPU v5e pod-slice); the multi-pod mesh adds a
 leading "pod" axis (2 pods = 512 chips) whose collectives ride DCN.
+
+Every axis is `AxisType.Auto`: the model code places activations with
+`with_sharding_constraint` (`ShardingRules.cs`) and lets GSPMD propagate
+the rest, which `jax.make_mesh`'s default Explicit axes would refuse.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_abstract_mesh(shape, axis_names):
-    """Version-tolerant AbstractMesh constructor.
-
-    jax >= 0.5 takes ``AbstractMesh(shape, axis_names)``; jax 0.4.x takes a
-    single tuple-of-(name, size) pairs.  Callers always pass the two-arg
-    form; we adapt.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
+def auto_mesh(shape, axis_names):
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    if multi_pod:
+        return auto_mesh((2, 16, 16), ("pod", "data", "model"))
+    return auto_mesh((16, 16), ("data", "model"))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """A small mesh over however many (host) devices exist — for tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
-
-
-def mesh_axes(mesh) -> dict:
-    names = mesh.axis_names
-    return {
-        "batch": tuple(n for n in ("pod", "data") if n in names),
-        "model": ("model",) if "model" in names else (),
-        "fsdp": tuple(n for n in ("pod", "data") if n in names),
-    }
+    return auto_mesh((data, model), ("data", "model"))

@@ -11,19 +11,17 @@ development, a pod for production).
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
-import pathlib
+import math
 import time
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import get_config, smoke_variant
 from repro.core.elastic import StragglerDetector
 from repro.core.streaming_checkpoint import StreamingCheckpointer
 from repro.data.pipeline import Prefetcher, StorageNodeDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.optim import OptimizerConfig, adamw_init
@@ -35,6 +33,11 @@ def train_loop(cfg, *, steps, batch, seq, ckpt_dir=None, ckpt_every=50,
                lr=3e-4, seed=0, log_every=10, data_mesh=1, model_mesh=1,
                resume=False, log_path=None, use_pallas=False,
                distribution="zipf_markov"):
+    """Train `cfg` for `steps` steps on data made from `seed`.
+
+    Returns (state, info).  info["step_times_s"] leaves out the first step
+    of the run, which compiles; that one is info["first_step_s"].
+    """
     mesh = None
     rules = None
     if data_mesh * model_mesh > 1:
@@ -42,17 +45,17 @@ def train_loop(cfg, *, steps, batch, seq, ckpt_dir=None, ckpt_every=50,
         rules = ShardingRules(mesh)
     opt_cfg = OptimizerConfig(lr=lr, warmup=max(10, steps // 20),
                               total_steps=steps)
-    params = M.init_params(jax.random.PRNGKey(seed), cfg, tp=model_mesh)
-    state = adamw_init(params, opt_cfg)
-    # unique buffers (fresh zeros can alias -> breaks donation)
-    state = jax.tree.map(jnp.array, state)
+
+    def init_state():
+        params = M.init_params(jax.random.PRNGKey(seed), cfg, tp=model_mesh)
+        return adamw_init(params, opt_cfg)
+    shardings = None
     if mesh is not None:
-        from jax.sharding import NamedSharding
-        sspec = state_specs(state, mesh)
-        state = jax.device_put(state, jax.tree.map(
-            lambda s: NamedSharding(mesh, s), sspec,
-            is_leaf=lambda x: isinstance(
-                x, jax.sharding.PartitionSpec)))
+        shardings = rules.to_shardings(
+            state_specs(jax.eval_shape(init_state), mesh))
+    # one program: every leaf gets a buffer of its own (donation needs
+    # that), born on its shards, with no second copy of the state
+    state = jax.jit(init_state, out_shardings=shardings)()
     ckpt = StreamingCheckpointer(ckpt_dir) if ckpt_dir else None
     if resume and ckpt and ckpt.latest_step() is not None:
         state = ckpt.restore(jax.eval_shape(lambda: state))
@@ -63,29 +66,37 @@ def train_loop(cfg, *, steps, batch, seq, ckpt_dir=None, ckpt_every=50,
                       donate_argnums=(0,))
     ds = StorageNodeDataset(vocab_size=cfg.vocab_size, seq_len=seq,
                             global_batch=batch, seed=seed,
+                            n_storage_nodes=math.gcd(batch, 4),
                             distribution=distribution)
+    batch_sharding = rules.batch_sharding() if rules else None
     detector = StragglerDetector(n_hosts=max(jax.process_count(), 1))
     logf = open(log_path, "a") if log_path else None
-    losses = []
-    it = Prefetcher(iter(ds), depth=2)
+    losses, step_times, first_step_s = [], [], None
+    it = Prefetcher(iter(ds), depth=2,
+                    put_fn=lambda b: jax.device_put(b, batch_sharding))
     t_start = time.perf_counter()
     start_step = int(state.step)
-    for batch_np in it:
+    for batch_dev in it:
         step = int(state.step)
         if step >= steps:
             break
         if step < start_step:
             continue
         t0 = time.perf_counter()
-        state, metrics = step_fn(state, batch_np)
+        state, metrics = step_fn(state, batch_dev)
         loss = float(metrics["loss"])
         dt = time.perf_counter() - t0
-        detector.observe([dt])
         losses.append(loss)
+        rec = {"step": step, "loss": round(loss, 4),
+               "step_time_s": round(dt, 3)}
+        if first_step_s is None:      # compiles: kept out of the rates
+            first_step_s = dt
+            rec["compiled"] = True
+        else:
+            step_times.append(dt)
+            detector.observe([dt])
+            rec["tokens_per_s"] = round(batch * seq / dt, 1)
         if step % log_every == 0:
-            rec = {"step": step, "loss": round(loss, 4),
-                   "step_time_s": round(dt, 3),
-                   "tokens_per_s": round(batch * seq / dt, 1)}
             print(json.dumps(rec), flush=True)
             if logf:
                 logf.write(json.dumps(rec) + "\n")
@@ -95,7 +106,9 @@ def train_loop(cfg, *, steps, batch, seq, ckpt_dir=None, ckpt_every=50,
     if ckpt:
         ckpt.save(int(state.step), state)
     wall = time.perf_counter() - t_start
-    return state, {"losses": losses, "wall_s": wall}
+    return state, {"losses": losses, "wall_s": wall,
+                   "first_step_s": first_step_s, "step_times_s": step_times,
+                   "batch_sharding": batch_dev["tokens"].sharding}
 
 
 def main():
@@ -115,6 +128,7 @@ def main():
     ap.add_argument("--log", default=None)
     ap.add_argument("--use-pallas", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_variant(cfg)

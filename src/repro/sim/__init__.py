@@ -57,7 +57,7 @@ from repro.sim.engine import (ALLOCATORS, Engine, EventKind, Resource,
                               SimEvent, SimResult, SimulationStalled,
                               Task, progressive_fill_rates,
                               water_filling_rates)
-from repro.sim.alloc import BACKENDS, SOLVERS, jit_available
+from repro.sim.alloc import BACKENDS, SOLVERS
 from repro.sim.calq import (TIMED_QUEUES, CalendarTimedQueue,
                             HeapTimedQueue, make_timed_queue)
 from repro.sim.topology import (Fabric, NodeModel, Topology,
@@ -90,7 +90,7 @@ from repro.sim.report import (append_bench_run, attach_attribution,
 from repro.sim import obs, sched
 
 __all__ = [
-    "ALLOCATORS", "BACKENDS", "SOLVERS", "TIMED_QUEUES", "jit_available",
+    "ALLOCATORS", "BACKENDS", "SOLVERS", "TIMED_QUEUES",
     "CalendarTimedQueue", "HeapTimedQueue", "make_timed_queue",
     "Engine", "EventKind", "Resource", "SimEvent",
     "SimResult", "SimulationStalled", "Task",

@@ -59,8 +59,8 @@ BACKENDS = ("array", "legacy")
 # "numpy" is `vector_water_fill`; "jit" routes large components through
 # `vector_water_fill_jit` (jax.jit over the same CSR arrays, bitwise
 # the same rates — see its docstring) and falls back to numpy for small
-# ones (below `_JIT_MIN_FLOWS`, dispatch overhead beats the kernel) or
-# when jax is not importable.  Mixing the two per component is safe
+# ones (below `_JIT_MIN_FLOWS`, dispatch overhead beats the kernel).
+# Mixing the two per component is safe
 # precisely because the rates are bitwise equal.
 SOLVERS = ("numpy", "jit")
 
@@ -141,26 +141,19 @@ def vector_water_fill(indptr: np.ndarray, indices: np.ndarray,
 # jax.jit water-fill (optional solver for the array core)
 # ---------------------------------------------------------------------------
 
-# probed lazily on first use: False when jax is not importable (the
-# engine then silently runs the numpy round loop — no hard dependency),
-# else the compiled kernel + the x64 context manager
-_JIT = {"ready": None}
+# built lazily on first use: the jitted kernel + the x64 context manager
+_JIT = {}
 
 
 def _next_pow2(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
-def _probe_jit() -> bool:
-    if _JIT["ready"] is None:
-        try:
-            import jax
-            from jax import lax
-            import jax.numpy as jnp
-            from jax.experimental import enable_x64
-        except Exception:               # jax absent or broken: numpy path
-            _JIT["ready"] = False  # simlint: ok[STATE001] memoized probe result
-            return False
+def _jit_kernel() -> dict:
+    if not _JIT:
+        import jax
+        from jax import lax
+        import jax.numpy as jnp
 
         def body(carry):
             remaining, live, rates, unpinned, n_left, pair_flow, \
@@ -206,20 +199,9 @@ def _probe_jit() -> bool:
         # simlint: ok[STATE001] compile-once cache of a pure kernel —
         # write-once per process, never consulted for sim state
         _JIT["fn"] = jax.jit(kernel, donate_argnums=(4,))  # simlint: ok[STATE001] see above
-        _JIT["x64"] = enable_x64  # simlint: ok[STATE001] see above
+        _JIT["x64"] = lambda: jax.enable_x64(True)  # simlint: ok[STATE001] see above
         _JIT["jnp"] = jnp  # simlint: ok[STATE001] see above
-        _JIT["ready"] = True  # simlint: ok[STATE001] see above
-    return _JIT["ready"]
-
-
-def jit_available() -> bool:
-    """True when the optional ``jax.jit`` water-fill kernel compiled.
-
-    `vector_water_fill_jit` (and ``solver="jit"``) silently falls back
-    to the numpy round loop when jax is absent, so benchmarks that
-    *label* a run "jit" must check this instead of trusting the label.
-    """
-    return _probe_jit()
+    return _JIT
 
 
 def vector_water_fill_jit(indptr: np.ndarray, indices: np.ndarray,
@@ -227,12 +209,11 @@ def vector_water_fill_jit(indptr: np.ndarray, indices: np.ndarray,
     """`vector_water_fill` with the round loop compiled by ``jax.jit``.
 
     The kernel replays the numpy allocator's float operation sequence
-    on float64 (under `jax.experimental.enable_x64`): per-round IEEE
+    on float64 (under `jax.enable_x64`): per-round IEEE
     divides for the fair shares, a selection min, exact-equality tie
     grouping, and the per-hold sequential capacity subtraction — so the
     returned rates are bitwise equal to `vector_water_fill` and the
-    solver choice never shows in an event trace.  Falls back to the
-    numpy implementation when jax is not importable.
+    solver choice never shows in an event trace.
 
     To bound recompilation, instances are padded to power-of-two
     (flows, pairs, resources) buckets with one dummy resource of
@@ -244,8 +225,6 @@ def vector_water_fill_jit(indptr: np.ndarray, indices: np.ndarray,
     nf = indptr.size - 1
     if nf == 0:
         return np.zeros(0)
-    if not _probe_jit():
-        return vector_water_fill(indptr, indices, cap)
     counts = np.diff(indptr)
     pair_flow = np.repeat(np.arange(nf), counts)
     nres = cap.size
@@ -267,12 +246,13 @@ def vector_water_fill_jit(indptr: np.ndarray, indices: np.ndarray,
     cap_full = np.concatenate([np.asarray(cap, dtype=float),
                                np.full(nres_pad - nres, np.inf)])
     live0 = np.bincount(idx_full, minlength=nres_pad)
-    jnp = _JIT["jnp"]
-    with _JIT["x64"]():
-        rates = _JIT["fn"](jnp.asarray(pf_full), jnp.asarray(idx_full),
-                           jnp.asarray(cap_full),
-                           jnp.asarray(live0),
-                           jnp.zeros(nf_pad))
+    jit = _jit_kernel()
+    jnp = jit["jnp"]
+    with jit["x64"]():
+        rates = jit["fn"](jnp.asarray(pf_full), jnp.asarray(idx_full),
+                          jnp.asarray(cap_full),
+                          jnp.asarray(live0),
+                          jnp.zeros(nf_pad))
         out = np.asarray(rates)
     return out[:nf]
 

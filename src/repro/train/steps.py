@@ -123,12 +123,11 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
         return sspec, bspec
 
     def wrapped(state, batch):
-        from repro.compat import shard_map
         sspec, bspec = make_specs(state, batch)
-        return shard_map(inner, mesh=mesh, in_specs=(sspec, bspec),
-                         out_specs=(sspec, jax.tree.map(
-                             lambda _: P(), {"loss": 0, "aux": 0})),
-                         axis_names={"pod"})(state, batch)
+        return jax.shard_map(inner, mesh=mesh, in_specs=(sspec, bspec),
+                             out_specs=(sspec, jax.tree.map(
+                                 lambda _: P(), {"loss": 0, "aux": 0})),
+                             axis_names={"pod"}, check_vma=False)(state, batch)
     return wrapped
 
 

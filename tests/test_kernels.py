@@ -113,3 +113,17 @@ def test_kernel_grads_flow():
     gref = jax.grad(lambda q: jnp.sum(
         flash_attention_ref(q, k, v) ** 2))(q)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gref), atol=1e-3)
+
+
+def test_kernels_interpret_only_on_the_cpu():
+    from repro.kernels.backend import interpret_mode
+    assert interpret_mode() is (jax.default_backend() == "cpu")
+    assert interpret_mode(False) is False and interpret_mode(True) is True
+
+
+def test_wkv6_refuses_to_compile():
+    """Asked for compiled, wkv6 raises rather than interpret or fall back."""
+    B, H, S, d = 1, 1, 64, 16
+    x = jnp.zeros((B, H, S, d))
+    with pytest.raises(NotImplementedError, match="does not compile"):
+        wkv6(x, x, x, x, jnp.zeros((H, d)), interpret=False)

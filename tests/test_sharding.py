@@ -3,17 +3,16 @@ must divide evenly.  Uses AbstractMesh — no devices required."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import ALL_ARCHS, SHAPES, get_config, supports_shape
-from repro.launch.mesh import make_abstract_mesh
 from repro.models import model as M
 from repro.optim import OptimizerConfig
 from repro.sharding.rules import ShardingRules, param_specs, state_specs
 from repro.train.steps import abstract_caches, abstract_state
 
-SINGLE = make_abstract_mesh((16, 16), ("data", "model"))
-MULTI = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axis_size(mesh, ax):

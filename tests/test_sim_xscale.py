@@ -2,12 +2,12 @@
 per-component min_dt counters, the per-phase timing shares, the
 `compare_engine_variants` harness, the engine's timed-queue/solver
 parameter validation, and the optional jax.jit water-fill solver
-(bitwise against the numpy round loop when jax is importable)."""
+(bitwise against the numpy round loop)."""
 import numpy as np
 import pytest
 
 from repro.sim import (Fabric, SOLVERS, SimulationStalled, TIMED_QUEUES,
-                       compare_engine_variants, jit_available,
+                       compare_engine_variants,
                        lovelock_cluster, phase_shares,
                        pipelined_shuffle_waves, shuffle)
 from repro.sim.alloc import (ArrayCore, vector_water_fill,
@@ -140,10 +140,9 @@ def test_compare_engine_variants_matrix():
 
     variants = {"heap": dict(backend="array", timed_queue="heap"),
                 "calendar": dict(backend="array",
-                                 timed_queue="calendar")}
-    if jit_available():
-        variants["jit"] = dict(backend="array", timed_queue="calendar",
-                               solver="jit")
+                                 timed_queue="calendar"),
+                "jit": dict(backend="array", timed_queue="calendar",
+                            solver="jit")}
     cmp = compare_engine_variants(make_topo, build, variants,
                                   repeats=2, prepare=prepare)
     for name in variants:
@@ -179,7 +178,6 @@ def _random_instance(rng, nf, nres):
             np.asarray(cap, dtype=np.float64))
 
 
-@pytest.mark.skipif(not jit_available(), reason="jax unavailable")
 @pytest.mark.parametrize("seed", range(5))
 def test_jit_water_fill_bitwise_matches_numpy(seed):
     rng = np.random.default_rng(seed)
@@ -201,7 +199,6 @@ def test_jit_water_fill_empty_and_fallback():
     assert empty.size == 0
 
 
-@pytest.mark.skipif(not jit_available(), reason="jax unavailable")
 def test_jit_solver_engine_trace_matches_numpy_solver():
     results = {}
     for solver in SOLVERS:
