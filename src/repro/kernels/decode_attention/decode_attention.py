@@ -66,8 +66,9 @@ def decode_attention_fwd(q, k, v, bias, *, bw=DEFAULT_BW, scale=None,
     nw = W // bw
     scale = scale or 1.0 / math.sqrt(d)
     kernel = functools.partial(_kernel, bw=bw, nw=nw, scale=scale)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid=(B, K, nw),
         in_specs=[
             pl.BlockSpec((1, 1, G, d), lambda b, kk, iw: (b, kk, 0, 0)),
@@ -83,4 +84,6 @@ def decode_attention_fwd(q, k, v, bias, *, bw=DEFAULT_BW, scale=None,
             pltpu.VMEM((G, d), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, bias)
+    )
+    with jax.named_scope("kernel"):
+        return call(q, k, v, bias)

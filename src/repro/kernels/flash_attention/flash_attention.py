@@ -86,6 +86,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
     """q (B,Sp,H,d), k/v (B,Sp,K,d); Sp must be a multiple of bq and bk.
 
     Returns o (B,Sp,H,d). seq_len: true (unpadded) length for key masking.
+    The transposes run under the scope `kv`, the kernel under `kernel`.
     """
     B, Sp, H, d = q.shape
     K = k.shape[2]
@@ -95,16 +96,17 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
     nq, nk = Sp // bq, Sp // bk
     scale = scale or 1.0 / math.sqrt(d)
 
-    # (B,S,H,d) -> (B,H,S,d) blocks
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
+    with jax.named_scope("kv"):         # (B,S,H,d) -> (B,H,S,d) blocks
+        qt = q.transpose(0, 2, 1, 3)
+        kt = k.transpose(0, 2, 1, 3)
+        vt = v.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(
         _kernel, bq=bq, bk=bk, nk=nk, seq_len=seq_len, causal=causal,
         window=window, scale=scale)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b, h, iq, ik: (b, h, iq, 0)),
@@ -122,5 +124,8 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=None,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
-    )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    )
+    with jax.named_scope("kernel"):
+        out = call(qt, kt, vt)
+    with jax.named_scope("kv"):
+        return out.transpose(0, 2, 1, 3)

@@ -1,6 +1,8 @@
 """jit'd wrapper: padding, dtype handling, custom_vjp.
 
 Forward runs the Pallas kernel: compiled on a TPU, interpreted on the CPU.
+The pads and slice-back run under the scope `kv`, as do the kernel's own
+transposes; the kernel runs under `kernel`.
 Backward recomputes through the jnp oracle (the kernel is forward-only;
 a backward flash kernel is still to be written).
 """
@@ -31,11 +33,13 @@ def _padded_call(q, k, v, causal, window, interpret):
     def pad(x, s_to, d_to):
         return jnp.pad(x, ((0, 0), (0, s_to - x.shape[1]), (0, 0),
                            (0, d_to - x.shape[3])))
-    qp, kp, vp = (pad(x, Sp, dp) for x in (q, k, v))
+    with jax.named_scope("kv"):
+        qp, kp, vp = (pad(x, Sp, dp) for x in (q, k, v))
     o = flash_attention_fwd(qp, kp, vp, causal=causal, window=window,
                             bq=bq, bk=bk, seq_len=S,
                             scale=1.0 / (d ** 0.5), interpret=interpret)
-    return o[:, :S, :, :d]
+    with jax.named_scope("kv"):
+        return o[:, :S, :, :d]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

@@ -77,8 +77,9 @@ def wkv6_fwd(r, k, v, logw, u, *, chunk=DEFAULT_CHUNK, interpret=False):
     assert S % chunk == 0, (S, chunk)
     nc = S // chunk
     kernel = functools.partial(_kernel, c=chunk, nc=nc)
-    o, sfin = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
+        name="wkv6",
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, d), lambda b, h, ic: (b, h, ic, 0)),
@@ -97,5 +98,7 @@ def wkv6_fwd(r, k, v, logw, u, *, chunk=DEFAULT_CHUNK, interpret=False):
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, logw, u)
+    )
+    with jax.named_scope("kernel"):
+        o, sfin = call(r, k, v, logw, u)
     return o, sfin

@@ -10,6 +10,11 @@ Conventions
 * Layers are written with jnp/lax only (scan/associative_scan for SSMs) so
   they lower under GSPMD; attention can be swapped for the Pallas kernel
   with cfg.use_pallas (TPU).
+* Attention names its device work with `jax.named_scope` (metadata only):
+  `qkv`, `rope`, `kv` (every op that moves K/V between the cache and the
+  kernel), `kernel` (the Pallas path; its wrapper adds `kv` and `kernel`
+  itself) or `core` (the XLA path), and `out`.  The caller puts them under
+  `attn` (`models/model.py`).
 """
 from __future__ import annotations
 
@@ -151,38 +156,45 @@ def self_attention(p, x, cfg, rules=None, *, causal=None, use_rope=True,
     """
     causal = cfg.causal if causal is None else causal
     B, S, D = x.shape
-    q, k, v = _qkv(p, x, x, cfg, rules)
-    positions = jnp.broadcast_to(
-        (0 if cache_index is None else cache_index)
-        + jnp.arange(S)[None, :], (B, S)).astype(jnp.int32)
-    if use_rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k, v = _qkv(p, x, x, cfg, rules)
+    with jax.named_scope("rope"):
+        positions = jnp.broadcast_to(
+            (0 if cache_index is None else cache_index)
+            + jnp.arange(S)[None, :], (B, S)).astype(jnp.int32)
+        if use_rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
     new_cache = None
     if kv_cache is not None:
-        ck, cv = kv_cache["k"], kv_cache["v"]
-        W = ck.shape[1]
-        if S >= W:                       # ring smaller than prefill: keep tail
-            start = (cache_index + S - W) % W
-            widx = (start + jnp.arange(W)) % W
-            ck = ck.at[:, widx].set(k[:, -W:].astype(ck.dtype))
-            cv = cv.at[:, widx].set(v[:, -W:].astype(cv.dtype))
-        else:
-            widx = (cache_index + jnp.arange(S)) % W
-            ck = ck.at[:, widx].set(k.astype(ck.dtype))
-            cv = cv.at[:, widx].set(v.astype(cv.dtype))
-        new_cache = {"k": ck, "v": cv}
-    if use_pallas:
+        with jax.named_scope("kv"):
+            ck, cv = kv_cache["k"], kv_cache["v"]
+            W = ck.shape[1]
+            if S >= W:                   # ring smaller than prefill: keep tail
+                start = (cache_index + S - W) % W
+                widx = (start + jnp.arange(W)) % W
+                ck = ck.at[:, widx].set(k[:, -W:].astype(ck.dtype))
+                cv = cv.at[:, widx].set(v[:, -W:].astype(cv.dtype))
+            else:
+                widx = (cache_index + jnp.arange(S)) % W
+                ck = ck.at[:, widx].set(k.astype(ck.dtype))
+                cv = cv.at[:, widx].set(v.astype(cv.dtype))
+            new_cache = {"k": ck, "v": cv}
+    if use_pallas:                       # the wrapper names its kv and kernel
         from repro.kernels.flash_attention import ops as fops
         o = fops.flash_attention(q, k, v, causal=causal,
                                  window=cfg.sliding_window)
     elif getattr(cfg, "attn_block", None):
-        o = _attn_core_chunked(q, k, v, positions, positions, causal,
-                               cfg.sliding_window, block=cfg.attn_block)
+        with jax.named_scope("core"):
+            o = _attn_core_chunked(q, k, v, positions, positions, causal,
+                                   cfg.sliding_window, block=cfg.attn_block)
     else:
-        bias = _mask_bias(positions, positions, causal, cfg.sliding_window)
-        o = _attn_core(q, k, v, bias, rules)
-    return _proj_out(p, o, rules), new_cache
+        with jax.named_scope("core"):
+            bias = _mask_bias(positions, positions, causal,
+                              cfg.sliding_window)
+            o = _attn_core(q, k, v, bias, rules)
+    with jax.named_scope("out"):
+        return _proj_out(p, o, rules), new_cache
 
 
 def decode_attention(p, x, cfg, rules=None, *, cache, cache_index,
@@ -190,32 +202,40 @@ def decode_attention(p, x, cfg, rules=None, *, cache, cache_index,
     """Single-token (Sq=1) self-attention over a KV cache (ring for SWA)."""
     B, S, D = x.shape
     assert S == 1
-    q, k, v = _qkv(p, x, x, cfg, rules)
-    pos = jnp.broadcast_to(cache_index[None, None]
-                           if jnp.ndim(cache_index) == 0 else cache_index,
-                           (B, 1)).astype(jnp.int32)
-    if use_rope:
-        q = rope(q, pos, cfg.rope_theta)
-        k = rope(k, pos, cfg.rope_theta)
-    ck, cv = cache["k"], cache["v"]
-    W = ck.shape[1]
-    slot = (cache_index % W).astype(jnp.int32)
-    ck = lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), slot, axis=1)
-    cv = lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), slot, axis=1)
-    new_cache = {"k": ck, "v": cv}
-    slots = jnp.arange(W)[None, :]
-    # ring semantics hold for full caches too: unwritten future slots get
-    # negative positions and are masked invalid.
-    kv_pos = cache_index - ((cache_index - slots) % W)
-    kv_pos = jnp.broadcast_to(kv_pos, (B, W)).astype(jnp.int32)
-    valid = (kv_pos >= 0) & (kv_pos <= cache_index)
-    bias = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)[:, None, :]
-    if use_pallas:
+    with jax.named_scope("qkv"):
+        q, k, v = _qkv(p, x, x, cfg, rules)
+    with jax.named_scope("rope"):
+        pos = jnp.broadcast_to(cache_index[None, None]
+                               if jnp.ndim(cache_index) == 0 else cache_index,
+                               (B, 1)).astype(jnp.int32)
+        if use_rope:
+            q = rope(q, pos, cfg.rope_theta)
+            k = rope(k, pos, cfg.rope_theta)
+    with jax.named_scope("kv"):
+        ck, cv = cache["k"], cache["v"]
+        W = ck.shape[1]
+        slot = (cache_index % W).astype(jnp.int32)
+        ck = lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), slot,
+                                             axis=1)
+        cv = lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), slot,
+                                             axis=1)
+        new_cache = {"k": ck, "v": cv}
+    with jax.named_scope("kernel" if use_pallas else "core"):
+        slots = jnp.arange(W)[None, :]
+        # ring semantics hold for full caches too: unwritten future slots get
+        # negative positions and are masked invalid.
+        kv_pos = cache_index - ((cache_index - slots) % W)
+        kv_pos = jnp.broadcast_to(kv_pos, (B, W)).astype(jnp.int32)
+        valid = (kv_pos >= 0) & (kv_pos <= cache_index)
+        bias = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)[:, None, :]
+    if use_pallas:                       # the wrapper names its kv and kernel
         from repro.kernels.decode_attention import ops as dops
         o = dops.decode_attention(q, ck, cv, bias[:, 0])
     else:
-        o = _attn_core(q, ck, cv, bias, rules)
-    return _proj_out(p, o, rules), new_cache
+        with jax.named_scope("core"):
+            o = _attn_core(q, ck, cv, bias, rules)
+    with jax.named_scope("out"):
+        return _proj_out(p, o, rules), new_cache
 
 
 def cross_attention(p, x, cfg, rules=None, *, kv=None, cache=None):

@@ -4,6 +4,15 @@ Layer stacks are grouped into *periods* (the repeating structural unit —
 e.g. Jamba's [7×mamba, 1×attn], the VLM's [4×self, 1×cross]) and scanned
 over `n_periods = num_layers // period`, so even the 126-layer 405B model
 lowers to a compact HLO.
+
+Device work is named with `jax.named_scope`, which is metadata only (the
+optimized HLO is the same without it) and shows in a profiler trace as each
+op's name path: `embed`, `norm`, `attn` (inside it `qkv`, `rope`, `kv`,
+`kernel` or `core`, `out`; `models/layers.py`), `mlp`, `unembed`, and
+`sample` (`train/steps.py`).  `attn/kv` also holds the per-layer cache
+slicing and write-back of the `cache_in_carry` decode loop.  The slicing of
+the scanned weights and caches that `lax.scan` itself emits carries no
+scope: a scope inside the body does not reach it.
 """
 from __future__ import annotations
 
@@ -273,26 +282,28 @@ def _apply_block(p, spec, x, cfg, rules, *, cache=None, cache_index=None,
             new_cache = {"tm": tm_state, "cm": cm_state}
         return x, new_cache, aux
 
-    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+    with jax.named_scope("norm"):
+        h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind == "attn":
         use_rope = not cfg.encoder_layers     # whisper: abs pos, no rope
-        if mode == "decode":
-            o, kvc = L.decode_attention(p["attn"], h, cfg, rules,
-                                        cache=cache["kv"],
-                                        cache_index=cache_index,
-                                        use_rope=use_rope,
-                                        use_pallas=use_pallas)
-            new_cache = {**cache, "kv": kvc}
-        else:
-            kvc_in = cache["kv"] if cache is not None else None
-            o, kvc = L.self_attention(p["attn"], h, cfg, rules,
-                                      causal=spec.get("causal", cfg.causal),
-                                      use_rope=use_rope,
-                                      kv_cache=kvc_in,
-                                      cache_index=0 if kvc_in is not None
-                                      else None, use_pallas=use_pallas)
-            if cache is not None:
+        with jax.named_scope("attn"):
+            if mode == "decode":
+                o, kvc = L.decode_attention(p["attn"], h, cfg, rules,
+                                            cache=cache["kv"],
+                                            cache_index=cache_index,
+                                            use_rope=use_rope,
+                                            use_pallas=use_pallas)
                 new_cache = {**cache, "kv": kvc}
+            else:
+                kvc_in = cache["kv"] if cache is not None else None
+                o, kvc = L.self_attention(
+                    p["attn"], h, cfg, rules,
+                    causal=spec.get("causal", cfg.causal), use_rope=use_rope,
+                    kv_cache=kvc_in,
+                    cache_index=0 if kvc_in is not None else None,
+                    use_pallas=use_pallas)
+                if cache is not None:
+                    new_cache = {**cache, "kv": kvc}
         if spec.get("cross"):            # whisper decoder cross-attn sublayer
             x = x + o
             h = L.rms_norm(x, p["ln_x"], cfg.norm_eps)
@@ -321,11 +332,13 @@ def _apply_block(p, spec, x, cfg, rules, *, cache=None, cache_index=None,
         if cache is not None:
             new_cache = {**cache, "mamba": mst}
     x = x + o
-    h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if spec["ffn"] == "moe":
-        o, aux = L.moe_ffn(p["moe"], h, cfg.moe, rules)
-    else:
-        o = L.swiglu(p["ffn"], h, rules)
+    with jax.named_scope("norm"):
+        h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        if spec["ffn"] == "moe":
+            o, aux = L.moe_ffn(p["moe"], h, cfg.moe, rules)
+        else:
+            o = L.swiglu(p["ffn"], h, rules)
     return x + o, new_cache, aux
 
 
@@ -349,18 +362,20 @@ def _run_encoder(params, cfg, frames, rules, use_pallas=False):
 
 
 def _embed(params, cfg, tokens, rules):
-    x = jnp.take(params["embed"], tokens, axis=0)
-    if rules is not None:
-        x = rules.cs(x, "act_bsd")
-    return x
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        if rules is not None:
+            x = rules.cs(x, "act_bsd")
+        return x
 
 
 def _unembed(params, cfg, x, rules):
-    head = params.get("lm_head", params["embed"])
-    logits = jnp.einsum("bsd,vd->bsv", x, head)
-    if rules is not None:
-        logits = rules.cs(logits, "logits_bsv")
-    return logits
+    with jax.named_scope("unembed"):
+        head = params.get("lm_head", params["embed"])
+        logits = jnp.einsum("bsd,vd->bsv", x, head)
+        if rules is not None:
+            logits = rules.cs(logits, "logits_bsv")
+        return logits
 
 
 def _prepare_extra(params, cfg, extra, rules, use_pallas=False):
@@ -407,7 +422,8 @@ def forward(params, cfg: ModelConfig, tokens, *, extra=None, rules=None,
     (x, aux), new_caches = lax.scan(
         body, (x, jnp.zeros((), jnp.float32)),
         (params["layers"], caches["layers"] if caches else None))
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("norm"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _unembed(params, cfg, x, rules)
     out_caches = None
     if caches is not None:
@@ -442,18 +458,20 @@ def decode_step(params, cfg: ModelConfig, token, caches, *, rules=None,
             x, cc, li = carry
             new_cc = []
             for i, spec in enumerate(specs):
-                ci = jax.tree.map(
-                    lambda l: lax.dynamic_index_in_dim(l, li, 0,
-                                                       keepdims=False),
-                    cc[i])
+                with jax.named_scope("attn/kv"):
+                    ci = jax.tree.map(
+                        lambda l: lax.dynamic_index_in_dim(l, li, 0,
+                                                           keepdims=False),
+                        cc[i])
                 x, nc, _ = _apply_block(pp[i], spec, x, cfg, rules,
                                         cache=ci, cache_index=index,
                                         mode="decode",
                                         use_pallas=use_pallas)
-                new_cc.append(jax.tree.map(
-                    lambda full, new: lax.dynamic_update_index_in_dim(
-                        full, new.astype(full.dtype), li, 0),
-                    cc[i], nc))
+                with jax.named_scope("attn/kv"):
+                    new_cc.append(jax.tree.map(
+                        lambda full, new: lax.dynamic_update_index_in_dim(
+                            full, new.astype(full.dtype), li, 0),
+                        cc[i], nc))
             return (x, new_cc, li + 1), None
 
         (x, new_layer_caches, _), _ = lax.scan(
@@ -474,7 +492,8 @@ def decode_step(params, cfg: ModelConfig, token, caches, *, rules=None,
         x, new_layer_caches = lax.scan(period_body, x,
                                        (params["layers"],
                                         caches["layers"]))
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("norm"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _unembed(params, cfg, x, rules)
     new_caches = dict(caches)
     new_caches["layers"] = new_layer_caches

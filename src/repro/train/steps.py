@@ -147,15 +147,16 @@ def make_serve_step(cfg: ModelConfig, rules=None, *, use_pallas=False,
         logits, caches = M.decode_step(params, cfg, token, caches,
                                        rules=rules, use_pallas=use_pallas,
                                        cache_in_carry=cache_in_carry)
-        if sample == "greedy":
-            Vp = logits.shape[-1]
-            if Vp != cfg.vocab_size:
-                logits = logits + jnp.where(
-                    jnp.arange(Vp) < cfg.vocab_size, 0.0, -1e30)
-            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        else:
-            nxt = token[:, -1]
-        return nxt[:, None], caches
+        with jax.named_scope("sample"):
+            if sample == "greedy":
+                Vp = logits.shape[-1]
+                if Vp != cfg.vocab_size:
+                    logits = logits + jnp.where(
+                        jnp.arange(Vp) < cfg.vocab_size, 0.0, -1e30)
+                nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            else:
+                nxt = token[:, -1]
+            return nxt[:, None], caches
     return serve_step
 
 
