@@ -91,10 +91,6 @@ def cell_c():
          "replicated over batch axes (225MB/chip at TP=16); predict the "
          "all-gather term collapses to ~0 and bottleneck flips to memory",
          tag="no_fsdp", fsdp=False, **base)
-    _run("C", 3, "cache-in-carry decode: thread KV caches through the "
-         "scan carry (in-place DUS) instead of ys; predict the full-cache "
-         "read+write per token disappears -> t_memory drops ~2-3x",
-         tag="no_fsdp_carry", fsdp=False, cache_in_carry=True, **base)
 
 
 def main():

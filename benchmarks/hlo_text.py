@@ -11,10 +11,11 @@ that the programs of two source trees can be compared with `cmp`:
 serve_static.py` builds them (the cell's configuration, batch, prompt length
 and cache, the Pallas kernels compiled, the caches donated).  Taken out:
 each op's `metadata={...}` (its `jax.named_scope` path and source line),
-the stack-frame tables, the numbers XLA appends to instruction names
-(`%name.12` becomes `%name#k`, k its order of first appearance), and the source
-locations inside each Pallas kernel's serialized Mosaic module (the module
-stands as the SHA-1 of its text without them).
+the stack-frame tables, the numbers and `.clone`s XLA appends to
+instruction names (`%name.12.clone` becomes `%name#k`, k its order of first
+appearance), and the source locations inside each Pallas kernel's
+serialized Mosaic module (the module stands as the SHA-1 of its text
+without them).
 """
 from __future__ import annotations
 
@@ -57,7 +58,7 @@ def canonical(hlo: str) -> str:
     ids: dict = {}
 
     def rename(m):
-        base = re.sub(r"(\.\d+)+$", "", m.group(0))
+        base = re.sub(r"(\.(\d+|clone))+$", "", m.group(0))
         return f"{base}#{ids.setdefault(m.group(0), len(ids))}"
     return "\n".join(re.sub(r"%[A-Za-z_][\w.\-]*", rename, line)
                      for line in out) + "\n"

@@ -64,8 +64,7 @@ def _batch_shardings(batch, rules):
 
 def lower_cell(arch: str, shape_name: str, mesh, *, grad_sync="gspmd",
                remat=True, compute_dtype=None, attn_block=None,
-               cfg_overrides=None, fsdp=True, cache_in_carry=False,
-               microbatches=1):
+               cfg_overrides=None, fsdp=True, microbatches=1):
     """Lower one cell; returns (lowered, aux_info)."""
     import dataclasses
     cfg = get_config(arch)
@@ -133,7 +132,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, grad_sync="gspmd",
                                   is_leaf=lambda x: isinstance(x, P))
             tok = input_specs(cfg, shape)["token"]
             tshard = _batch_shardings({"token": tok}, rules)["token"]
-            fn = make_serve_step(cfg, rules, cache_in_carry=cache_in_carry)
+            fn = make_serve_step(cfg, rules)
             lowered = jax.jit(fn, in_shardings=(pshard, cshard, tshard),
                               out_shardings=(tshard, cshard)).lower(
                 params, caches, tok)
@@ -143,8 +142,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, grad_sync="gspmd",
 def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
              grad_sync="gspmd", remat=True, save=True, tag="",
              compute_dtype=None, attn_block=None,
-             cfg_overrides=None, fsdp=True, cache_in_carry=False,
-             microbatches=1) -> dict:
+             cfg_overrides=None, fsdp=True, microbatches=1) -> dict:
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     ok, why = supports_shape(cfg, shape)
@@ -163,7 +161,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
                               remat=remat, compute_dtype=compute_dtype,
                               attn_block=attn_block,
                               cfg_overrides=cfg_overrides, fsdp=fsdp,
-                              cache_in_carry=cache_in_carry,
                               microbatches=microbatches)
     t1 = time.perf_counter()
     compiled = lowered.compile()

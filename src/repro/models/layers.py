@@ -11,10 +11,11 @@ Conventions
   they lower under GSPMD; attention can be swapped for the Pallas kernel
   with cfg.use_pallas (TPU).
 * Attention names its device work with `jax.named_scope` (metadata only):
-  `qkv`, `rope`, `kv` (every op that moves K/V between the cache and the
-  kernel), `kernel` (the Pallas path; its wrapper adds `kv` and `kernel`
-  itself) or `core` (the XLA path), and `out`.  The caller puts them under
-  `attn` (`models/model.py`).
+  `qkv`, `rope`, `kv` (prefill's cache fill; the flash wrapper's layout),
+  `kernel` (the Pallas path; the flash wrapper adds `kv` and `kernel`
+  itself; the decode kernel also writes the token's cache slot) or `core`
+  (the XLA path, the decode slot write included), and `out`.  The caller
+  puts them under `attn` (`models/model.py`).
 """
 from __future__ import annotations
 
@@ -145,41 +146,48 @@ def _proj_out(p, o, rules=None):
     return out
 
 
+def _ring_slots(cfg, Wp):
+    """How many of a self-attention cache's Wp slots its ring uses: the
+    window's worth, or all of them where the window is wider (the cache is
+    then sized to the sequence and never wraps)."""
+    return min(cfg.sliding_window or Wp, Wp)
+
+
 def self_attention(p, x, cfg, rules=None, *, causal=None, use_rope=True,
-                   kv_cache=None, cache_index=None, use_pallas=False):
+                   kv_cache=None, layer=None, use_pallas=False):
     """Self-attention over a full sequence (train / prefill).
 
     p: {wq (D,H',hd), wk/wv (D,K',hd), wo (H',hd,D), [qn, kn (hd,)]}
-    If kv_cache given, writes the (tail of the) new K/V into it at
-    cache_index and returns (out, new_cache); attention itself always runs
-    over the freshly computed full-sequence K/V.
+    If kv_cache ({k, v}: (L,B,K',hd,Wp), stacked over layers) is given, the
+    sequence's K/V (its last ring's worth) is written into layer `layer`
+    from position 0, and (out, new_cache) is returned; attention itself
+    always runs over the freshly computed full-sequence K/V.
     """
     causal = cfg.causal if causal is None else causal
     B, S, D = x.shape
     with jax.named_scope("qkv"):
         q, k, v = _qkv(p, x, x, cfg, rules)
     with jax.named_scope("rope"):
-        positions = jnp.broadcast_to(
-            (0 if cache_index is None else cache_index)
-            + jnp.arange(S)[None, :], (B, S)).astype(jnp.int32)
+        positions = jnp.broadcast_to(jnp.arange(S)[None, :],
+                                     (B, S)).astype(jnp.int32)
         if use_rope:
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
     new_cache = None
     if kv_cache is not None:
         with jax.named_scope("kv"):
-            ck, cv = kv_cache["k"], kv_cache["v"]
-            W = ck.shape[1]
-            if S >= W:                   # ring smaller than prefill: keep tail
-                start = (cache_index + S - W) % W
-                widx = (start + jnp.arange(W)) % W
-                ck = ck.at[:, widx].set(k[:, -W:].astype(ck.dtype))
-                cv = cv.at[:, widx].set(v[:, -W:].astype(cv.dtype))
-            else:
-                widx = (cache_index + jnp.arange(S)) % W
-                ck = ck.at[:, widx].set(k.astype(ck.dtype))
-                cv = cv.at[:, widx].set(v.astype(cv.dtype))
-            new_cache = {"k": ck, "v": cv}
+            Wp = kv_cache["k"].shape[-1]
+            R = _ring_slots(cfg, Wp)
+            n = min(S, R)                # positions S-n .. S-1 stay,
+            new_cache = {}               # position t in slot t % R; the
+            for name, t in (("k", k), ("v", v)):   # layer is written whole
+                c = kv_cache[name]
+                t = t[:, S - n:].transpose(0, 2, 3, 1)[None].astype(c.dtype)
+                if S > R:
+                    t = jnp.roll(t, S % R, axis=-1)
+                t = jnp.pad(t, ((0, 0),) * 4 + ((0, Wp - n),))
+                new_cache[name] = lax.dynamic_update_slice(
+                    c, t, (layer, 0, 0, 0, 0))
     if use_pallas:                       # the wrapper names its kv and kernel
         from repro.kernels.flash_attention import ops as fops
         o = fops.flash_attention(q, k, v, causal=causal,
@@ -197,9 +205,15 @@ def self_attention(p, x, cfg, rules=None, *, causal=None, use_rope=True,
         return _proj_out(p, o, rules), new_cache
 
 
-def decode_attention(p, x, cfg, rules=None, *, cache, cache_index,
+def decode_attention(p, x, cfg, rules=None, *, cache, layer, cache_index,
                      use_rope=True, use_pallas=False):
-    """Single-token (Sq=1) self-attention over a KV cache (ring for SWA)."""
+    """Single-token (Sq=1) self-attention over layer `layer` of the stacked
+    KV caches {k, v}: (L,B,K',hd,Wp), a ring of slots on the minor axis.
+
+    The token's K/V is written in place, into its slot of that layer, and
+    the attention reads the stacked caches where they lie: the kernel does
+    both (under `kernel`), the XLA path both under `core`.
+    """
     B, S, D = x.shape
     assert S == 1
     with jax.named_scope("qkv"):
@@ -211,31 +225,28 @@ def decode_attention(p, x, cfg, rules=None, *, cache, cache_index,
         if use_rope:
             q = rope(q, pos, cfg.rope_theta)
             k = rope(k, pos, cfg.rope_theta)
-    with jax.named_scope("kv"):
-        ck, cv = cache["k"], cache["v"]
-        W = ck.shape[1]
-        slot = (cache_index % W).astype(jnp.int32)
-        ck = lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), slot,
-                                             axis=1)
-        cv = lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), slot,
-                                             axis=1)
-        new_cache = {"k": ck, "v": cv}
     with jax.named_scope("kernel" if use_pallas else "core"):
-        slots = jnp.arange(W)[None, :]
-        # ring semantics hold for full caches too: unwritten future slots get
-        # negative positions and are masked invalid.
-        kv_pos = cache_index - ((cache_index - slots) % W)
-        kv_pos = jnp.broadcast_to(kv_pos, (B, W)).astype(jnp.int32)
-        valid = (kv_pos >= 0) & (kv_pos <= cache_index)
-        bias = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)[:, None, :]
-    if use_pallas:                       # the wrapper names its kv and kernel
+        Wp = cache["k"].shape[-1]
+        R = _ring_slots(cfg, Wp)
+        slot = (cache_index % R).astype(jnp.int32)
+        slots = jnp.arange(Wp)
+        # slot s holds the latest position t <= cache_index with t % R == s;
+        # slots never written yet get negative positions, and slots past the
+        # ring none: both are masked.
+        kv_pos = cache_index - ((cache_index - slots) % R)
+        valid = (slots < R) & (kv_pos >= 0)
+        bias = jnp.broadcast_to(jnp.where(valid, 0.0, -1e30),
+                                (B, Wp)).astype(jnp.float32)
+    args = (q, k, v, cache["k"], cache["v"], bias, layer, slot)
+    if use_pallas:                       # the wrapper names its kernel
         from repro.kernels.decode_attention import ops as dops
-        o = dops.decode_attention(q, ck, cv, bias[:, 0])
+        o, ck, cv = dops.decode_attention(*args)
     else:
+        from repro.kernels.decode_attention import decode_attention_ref
         with jax.named_scope("core"):
-            o = _attn_core(q, ck, cv, bias, rules)
+            o, ck, cv = decode_attention_ref(*args)
     with jax.named_scope("out"):
-        return _proj_out(p, o, rules), new_cache
+        return _proj_out(p, o, rules), {"k": ck, "v": cv}
 
 
 def cross_attention(p, x, cfg, rules=None, *, kv=None, cache=None):

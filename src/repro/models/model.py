@@ -9,10 +9,10 @@ Device work is named with `jax.named_scope`, which is metadata only (the
 optimized HLO is the same without it) and shows in a profiler trace as each
 op's name path: `embed`, `norm`, `attn` (inside it `qkv`, `rope`, `kv`,
 `kernel` or `core`, `out`; `models/layers.py`), `mlp`, `unembed`, and
-`sample` (`train/steps.py`).  `attn/kv` also holds the per-layer cache
-slicing and write-back of the `cache_in_carry` decode loop.  The slicing of
-the scanned weights and caches that `lax.scan` itself emits carries no
-scope: a scope inside the body does not reach it.
+`sample` (`train/steps.py`).  The slicing of the scanned weights and
+caches that `lax.scan` itself emits carries no scope: a scope inside the
+body does not reach it.  The self-attention caches are not scanned: they
+ride in the scan's carry, and `attn/kv` holds every op on them.
 """
 from __future__ import annotations
 
@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.kernels.decode_attention import cache_width
 from repro.models import layers as L
 
 Pytree = Any
@@ -262,9 +263,14 @@ def init_params(key, cfg: ModelConfig, tp: int = 1) -> Pytree:
 # ---------------------------------------------------------------------------
 
 
-def _apply_block(p, spec, x, cfg, rules, *, cache=None, cache_index=None,
-                 mode="train", extra=None, use_pallas=False):
-    """One block. Returns (x, new_cache, aux)."""
+def _apply_block(p, spec, x, cfg, rules, *, cache=None, layer=None,
+                 cache_index=None, mode="train", extra=None,
+                 use_pallas=False):
+    """One block. Returns (x, new_cache, aux).
+
+    `cache` holds this layer's slice of the scanned caches and the whole
+    stack of the self-attention caches (`kv`), of which layer `layer` is
+    this block's (see `_carried`)."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
     kind = spec["kind"]
@@ -289,19 +295,17 @@ def _apply_block(p, spec, x, cfg, rules, *, cache=None, cache_index=None,
         with jax.named_scope("attn"):
             if mode == "decode":
                 o, kvc = L.decode_attention(p["attn"], h, cfg, rules,
-                                            cache=cache["kv"],
+                                            cache=cache["kv"], layer=layer,
                                             cache_index=cache_index,
                                             use_rope=use_rope,
                                             use_pallas=use_pallas)
                 new_cache = {**cache, "kv": kvc}
             else:
-                kvc_in = cache["kv"] if cache is not None else None
                 o, kvc = L.self_attention(
                     p["attn"], h, cfg, rules,
                     causal=spec.get("causal", cfg.causal), use_rope=use_rope,
-                    kv_cache=kvc_in,
-                    cache_index=0 if kvc_in is not None else None,
-                    use_pallas=use_pallas)
+                    kv_cache=cache["kv"] if cache is not None else None,
+                    layer=layer, use_pallas=use_pallas)
                 if cache is not None:
                     new_cache = {**cache, "kv": kvc}
         if spec.get("cross"):            # whisper decoder cross-attn sublayer
@@ -401,46 +405,49 @@ def forward(params, cfg: ModelConfig, tokens, *, extra=None, rules=None,
     if cfg.encoder_layers:                      # whisper decoder abs pos
         x = x + _sinusoid(x.shape[1], cfg.d_model).astype(x.dtype)
 
+    kv, scanned = _carried(caches, specs)
+
     def period_body(carry, xs):
-        x, aux = carry
+        x, aux, kv, li = carry
         pp, cc = xs
-        new_cc = []
+        kv, new_cc = list(kv), []
         for i, spec in enumerate(specs):
-            x, nc, a = _apply_block(pp[i], spec, x, cfg, rules,
-                                    cache=None if cc is None else cc[i],
-                                    mode="train", extra=extra,
+            cache = None if cc is None else {**cc[i], **kv[i]}
+            x, nc, a = _apply_block(pp[i], spec, x, cfg, rules, cache=cache,
+                                    layer=li, mode="train", extra=extra,
                                     use_pallas=use_pallas)
+            if nc is not None:
+                kv[i], nc = _split(nc)
             new_cc.append(nc)
             aux = aux + a
-        return (x, aux), new_cc
+        return (x, aux, kv, li + 1), new_cc
 
     body = period_body
     if remat:
         body = jax.checkpoint(
             period_body,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    (x, aux), new_caches = lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)),
-        (params["layers"], caches["layers"] if caches else None))
+    (x, aux, kv, _), new_scanned = lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), kv, jnp.zeros((), jnp.int32)),
+        (params["layers"], scanned))
     with jax.named_scope("norm"):
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _unembed(params, cfg, x, rules)
     out_caches = None
     if caches is not None:
         out_caches = dict(caches)
-        out_caches["layers"] = new_caches
+        out_caches["layers"] = _joined(kv, new_scanned)
         out_caches["index"] = caches["index"] + tokens.shape[1]
     return logits, aux, out_caches
 
 
 def decode_step(params, cfg: ModelConfig, token, caches, *, rules=None,
-                use_pallas=False, cache_in_carry=False):
+                use_pallas=False):
     """One-token decode. token (B,1) int32. Returns (logits, new_caches).
 
-    cache_in_carry=True threads the KV caches through the scan *carry*
-    (dynamic-slice per layer + in-place dynamic-update) instead of the
-    scan ys — XLA aliases the carry buffer, so per-token HBM write traffic
-    is O(new slot) rather than O(whole cache).  See EXPERIMENTS §Perf/C.
+    The self-attention caches ride in the layer scan's carry: each layer
+    writes its token's slot in place and reads its layer of the stack where
+    it lies, so no layer's cache is sliced, copied or written back.
     """
     specs = block_specs(cfg)
     index = caches["index"]
@@ -452,53 +459,53 @@ def decode_step(params, cfg: ModelConfig, token, caches, *, rules=None,
         ang = pos / jnp.power(10000.0, 2 * i / D)
         pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], -1)
         x = x + pe.astype(x.dtype)
+    kv, scanned = _carried(caches, specs)
 
-    if cache_in_carry:
-        def body_carry(carry, pp):
-            x, cc, li = carry
-            new_cc = []
-            for i, spec in enumerate(specs):
-                with jax.named_scope("attn/kv"):
-                    ci = jax.tree.map(
-                        lambda l: lax.dynamic_index_in_dim(l, li, 0,
-                                                           keepdims=False),
-                        cc[i])
-                x, nc, _ = _apply_block(pp[i], spec, x, cfg, rules,
-                                        cache=ci, cache_index=index,
-                                        mode="decode",
-                                        use_pallas=use_pallas)
-                with jax.named_scope("attn/kv"):
-                    new_cc.append(jax.tree.map(
-                        lambda full, new: lax.dynamic_update_index_in_dim(
-                            full, new.astype(full.dtype), li, 0),
-                        cc[i], nc))
-            return (x, new_cc, li + 1), None
+    def period_body(carry, xs):
+        x, kv, li = carry
+        pp, cc = xs
+        kv, new_cc = list(kv), []
+        for i, spec in enumerate(specs):
+            x, nc, _ = _apply_block(pp[i], spec, x, cfg, rules,
+                                    cache={**cc[i], **kv[i]}, layer=li,
+                                    cache_index=index, mode="decode",
+                                    use_pallas=use_pallas)
+            kv[i], nc = _split(nc)
+            new_cc.append(nc)
+        return (x, kv, li + 1), new_cc
 
-        (x, new_layer_caches, _), _ = lax.scan(
-            body_carry, (x, caches["layers"], jnp.zeros((), jnp.int32)),
-            params["layers"])
-    else:
-        def period_body(x, xs):
-            pp, cc = xs
-            new_cc = []
-            for i, spec in enumerate(specs):
-                x, nc, _ = _apply_block(pp[i], spec, x, cfg, rules,
-                                        cache=cc[i], cache_index=index,
-                                        mode="decode",
-                                        use_pallas=use_pallas)
-                new_cc.append(nc)
-            return x, new_cc
-
-        x, new_layer_caches = lax.scan(period_body, x,
-                                       (params["layers"],
-                                        caches["layers"]))
+    (x, kv, _), new_scanned = lax.scan(
+        period_body, (x, kv, jnp.zeros((), jnp.int32)),
+        (params["layers"], scanned))
     with jax.named_scope("norm"):
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _unembed(params, cfg, x, rules)
     new_caches = dict(caches)
-    new_caches["layers"] = new_layer_caches
+    new_caches["layers"] = _joined(kv, new_scanned)
     new_caches["index"] = index + 1
     return logits, new_caches
+
+
+def _split(c: dict) -> tuple:
+    """One position's caches -> (the self-attention K/V, stacked over the
+    layers, which the layer scan carries and updates in place; the rest,
+    which it slices per layer: recurrent states and the read-only
+    cross-attention K/V, all small)."""
+    return ({n: t for n, t in c.items() if n == "kv"},
+            {n: t for n, t in c.items() if n != "kv"})
+
+
+def _carried(caches, specs) -> tuple:
+    """`_split` of every position of a period: (carried, scanned), the
+    latter None without caches."""
+    if caches is None:
+        return [{} for _ in specs], None
+    kv, rest = zip(*map(_split, caches["layers"]))
+    return list(kv), list(rest)
+
+
+def _joined(kv, scanned) -> list:
+    return [{**s, **k} for k, s in zip(kv, scanned)]
 
 
 # ---------------------------------------------------------------------------
@@ -508,18 +515,26 @@ def decode_step(params, cfg: ModelConfig, token, caches, *, rules=None,
 
 def init_caches(cfg: ModelConfig, batch: int, max_len: int, tp: int = 1,
                 dtype=jnp.bfloat16, cross_len: Optional[int] = None) -> Pytree:
+    """Zeroed caches for `batch` rows of up to `max_len` positions.
+
+    A self-attention cache `kv` is a ring of min(max_len, window) slots,
+    held as (n_periods, batch, Kp, hd, Wp): the slots on the minor axis,
+    padded to the decode kernel's `cache_width`; slots past the ring are
+    never written and always masked.  An index back at 0 starts a new
+    sequence: the slots it has not written yet are masked too.
+    """
     specs = block_specs(cfg)
     P = period_of(cfg)
     n_periods = cfg.num_layers // P
     hd = cfg.head_dim_()
     _, Kp, _ = cfg.padded_heads(tp)
-    W = min(max_len, cfg.sliding_window or max_len)
+    Wp = cache_width(min(max_len, cfg.sliding_window or max_len))
 
     def one(spec):
         c: dict = {}
-        if spec["kind"] == "attn":
-            c["kv"] = {"k": jnp.zeros((n_periods, batch, W, Kp, hd), dtype),
-                       "v": jnp.zeros((n_periods, batch, W, Kp, hd), dtype)}
+        if spec["kind"] == "attn":      # the decode kernel's layout
+            c["kv"] = {"k": jnp.zeros((n_periods, batch, Kp, hd, Wp), dtype),
+                       "v": jnp.zeros((n_periods, batch, Kp, hd, Wp), dtype)}
             if spec.get("cross"):
                 T = cross_len or cfg.num_audio_frames
                 c["xkv"] = {

@@ -201,8 +201,11 @@ class ShardingRules:
             s = _path_str(path)
             if leaf.ndim == 0:
                 return P()
-            if s.endswith("/k") or s.endswith("/v"):
-                # (n_periods, B, W, Kp, hd)
+            if s.endswith("/kv/k") or s.endswith("/kv/v"):
+                # self-attention ring (n_periods, B, Kp, hd, Wp)
+                spec = P(None, B, "model", None, S)
+            elif s.endswith("/k") or s.endswith("/v"):
+                # cross-attention source (n_periods, B, T, Kp, hd)
                 spec = P(None, B, S, "model", None)
             elif "mamba/conv" in s:
                 spec = P(None, B, None, "model")

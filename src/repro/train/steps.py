@@ -142,11 +142,10 @@ def make_prefill(cfg: ModelConfig, rules=None, *, use_pallas=False):
 
 
 def make_serve_step(cfg: ModelConfig, rules=None, *, use_pallas=False,
-                    sample: str = "greedy", cache_in_carry=False):
+                    sample: str = "greedy"):
     def serve_step(params, caches, token):
         logits, caches = M.decode_step(params, cfg, token, caches,
-                                       rules=rules, use_pallas=use_pallas,
-                                       cache_in_carry=cache_in_carry)
+                                       rules=rules, use_pallas=use_pallas)
         with jax.named_scope("sample"):
             if sample == "greedy":
                 Vp = logits.shape[-1]

@@ -13,6 +13,7 @@ import contextlib
 import dataclasses
 import functools
 import importlib.util
+import math
 import os
 import pathlib
 import re
@@ -23,6 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.kernels.decode_attention import cache_width
 from repro.kernels.decode_attention import ops as decode_ops
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.flash_attention import ops as flash_ops
@@ -87,12 +89,15 @@ def test_flash_forward_backward_compiles(one_chip):
 
 def test_decode_attention_compiles_batch_8(one_chip):
     B, W = 8, 2176            # serve cache: prompt 2048 + 128 generated
+    Wp = cache_width(W)
     q = _sds((B, 1, H, D), one_chip)
-    kv = _sds((B, W, K, D), one_chip)
-    bias = _sds((B, W), one_chip, jnp.float32)
-    txt = jax.jit(lambda q, k, v, b: decode_attention(
-        q, k, v, b, interpret=False)).lower(q, kv, kv, bias).compile(
-    ).as_text()
+    new = _sds((B, 1, K, D), one_chip)
+    kv = _sds((24, B, K, D, Wp), one_chip)     # the 24 layers' stacked ring
+    bias = _sds((B, Wp), one_chip, jnp.float32)
+    index = _sds((), one_chip, jnp.int32)
+    txt = jax.jit(functools.partial(decode_attention, interpret=False),
+                  donate_argnums=(3, 4)).lower(
+        q, new, new, kv, kv, bias, index, index).compile().as_text()
     assert KERNEL in txt
 
 
@@ -154,12 +159,14 @@ def test_serving_programs_carry_every_scope():
                               num_kv_heads=2, head_dim=64, d_ff=512,
                               vocab_size=512)
     prefill, step = serving_programs(cfg, use_pallas=False, P=32, G=8)
-    attn = {f"attn/{s}" for s in ("qkv", "rope", "kv", "core", "out")}
-    for lowered, extra in ((prefill, set()), (step, {"sample"})):
+    attn = {f"attn/{s}" for s in ("qkv", "rope", "core", "out")}
+    # the decode step writes its cache slot under attn/core
+    for lowered, extra, kv in ((prefill, set(), {"attn/kv"}),
+                               (step, {"sample"}, set())):
         paths = scope_paths(lowered.as_text(dialect="hlo", debug_info=True))
         heads = {p.split("/")[0] for p in paths}
         assert set(TOP_SCOPES) | extra <= heads, sorted(paths)
-        assert attn <= {"/".join(p.split("/")[:2]) for p in paths}
+        assert attn | kv <= {"/".join(p.split("/")[:2]) for p in paths}
 
 
 @pytest.fixture
@@ -178,9 +185,8 @@ def compiled_kernels(monkeypatch):
 def test_serving_programs_keep_scopes_compiled(one_chip, compiled_kernels):
     # a 2-layer h2o-danube at published widths, with the Pallas kernels
     prefill, step = serving_programs(DANUBE_2L, one_chip, use_pallas=True)
-    for lowered, kernel, write in ((prefill, "flash_attention", "scatter"),
-                                   (step, "decode_attention",
-                                    "dynamic-update-slice")):
+    for lowered, kernel, outside in ((prefill, "flash_attention", True),
+                                     (step, "decode_attention", False)):
         lines = lowered.compile().as_text().splitlines()
         # a trace shows a custom call by its instruction's name, and the
         # per-layer readers look the kernels up by that name
@@ -188,15 +194,100 @@ def test_serving_programs_keep_scopes_compiled(one_chip, compiled_kernels):
         assert calls and all(
             re.match(rf"\s*%{kernel}\.\d+ = ", ln) for ln in calls), calls
         assert all("/attn/" in ln and "/kernel/" in ln for ln in calls)
-        # the wrapper's pads and the layer's cache writes sit under attn/kv
-        # (the layer scan's own write-back of its results carries no scope)
+        # prefill: the flash wrapper's pads and the cache fill sit under
+        # attn/kv; decode: the kernel reads the cache as it lies, unpadded,
+        # and writes the token's slot itself
         pads = [ln for ln in lines
                 if f"/jit({kernel})/" in ln and re.search(r" pad\(", ln)]
         writes = [ln for ln in lines
-                  if f" {write}(" in ln and "/closed_call/" in ln]
-        assert pads and writes, (len(pads), len(writes))
+                  if " dynamic-update-slice(" in ln and "/closed_call/" in ln]
+        assert bool(pads) == bool(writes) == outside, (len(pads),
+                                                       len(writes))
         assert all("/attn/kv/" in ln or "/attn/jit" in ln and "/kv/" in ln
                    for ln in pads + writes)
+
+
+def _instructions(hlo: str):
+    """(computation, is root, name, opcode, output shapes, operand names,
+    line) of every instruction of an optimized program."""
+    comp = None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s*(ROOT )?%(\S+) = (.*)", line)
+        if not m:
+            continue
+        rest = " " + m.group(3)
+        op = re.search(r"\s([a-z][a-z0-9\-]*)\(", rest)
+        shapes = [tuple(int(n) for n in dims.split(",") if n)
+                  for dims in re.findall(r"\w+\[([\d,]*)\]",
+                                         rest[:op.start()])]
+        args = re.findall(r"%([\w.\-]+)", rest[op.end():].split(")")[0])
+        yield comp, bool(m.group(1)), m.group(2), op.group(1), shapes, args, \
+            line
+
+
+_NO_DATA = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+            "constant", "custom-call"}
+
+
+def cache_sized_ops(hlo: str, n: int, B: int, K: int) -> list:
+    """Ops that the device runs (not the insides of a fusion) with an
+    output shaped like a cache, with a batch axis of B and a kv-head axis of
+    K, of n or more elements; less those that move no data and the in-place
+    updates of fewer than n elements (a dynamic-update-slice, alone or as a
+    fusion's root)."""
+    def cache_like(shape):
+        rest = list(shape)
+        for a in (B, K):
+            if a not in rest:
+                return False
+            rest.remove(a)
+        return math.prod(shape) >= n
+    ins = list(_instructions(hlo))
+    size = {name: max(map(math.prod, s), default=0)
+            for _, _, name, _, s, _, _ in ins}
+    fused = {c for *_, ln in ins if " fusion(" in ln
+             for c in re.findall(r"calls=%([\w.\-]+)", ln)}
+    root = {c: (op, args) for c, r, _, op, _, args, _ in ins if r}
+    out = []
+    for comp, _, _, op, shapes, args, ln in ins:
+        if comp in fused or op in _NO_DATA or not any(map(cache_like,
+                                                          shapes)):
+            continue
+        if op == "fusion":
+            op, args = root[re.search(r"calls=%([\w.\-]+)", ln).group(1)]
+        if op == "dynamic-update-slice" and size[args[1]] < n:
+            continue
+        out.append(ln[:160])
+    return out
+
+
+@pytest.mark.parametrize("arch,layers,B,W", [
+    ("h2o-danube-1.8b", 24, 32, 1276),        # danube-decode
+    ("deepseek-coder-33b", 8, 8, 1560),       # deepseek-codecomp
+])
+def test_decode_step_moves_no_layer_cache(one_chip, compiled_kernels, arch,
+                                          layers, B, W):
+    """The decode step at a chip cell's cache shapes: outside the kernel, no
+    op (copy, pad, transpose, slice, fusion) outputs as many elements as one
+    layer's K or V cache, but for the in-place write of the token's slot.
+    (The layer scan's slices of the weights are not the caches'.)"""
+    cfg = dataclasses.replace(get_config(arch), num_layers=layers)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    params, caches = (
+        jax.tree.map(lambda t: sds(t.shape, t.dtype), tree) for tree in (
+            jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0),
+                                                 cfg)),
+            jax.eval_shape(lambda: M.init_caches(cfg, B, W))))
+    hlo = jax.jit(make_serve_step(cfg, use_pallas=True),
+                  donate_argnums=(1,)).lower(
+        params, caches, sds((B, 1), jnp.int32)).compile().as_text()
+    assert KERNEL in hlo
+    K = cfg.num_kv_heads
+    assert cache_sized_ops(hlo, B * K * cfg.head_dim_() * W, B, K) == []
 
 
 def _hlo_text():

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels.decode_attention import decode_attention, \
+from repro.kernels.decode_attention import cache_width, decode_attention, \
     decode_attention_ref
 from repro.kernels.flash_attention import flash_attention, \
     flash_attention_ref
@@ -57,21 +57,55 @@ def test_flash_attention_property(B, S, HK, d):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,W,H,K,d", [
-    (2, 512, 4, 2, 64), (1, 300, 8, 8, 128), (2, 1000, 4, 1, 80),
+@pytest.mark.parametrize("B,W,H,K,d,L,layer,index", [
+    (2, 512, 4, 2, 64, 2, 1, 300),     # ring not full yet
+    (1, 300, 8, 8, 128, 1, 0, 700),    # W < Wp 384, wrapped
+    (2, 1000, 4, 1, 80, 3, 2, 1500),   # d 80, W < Wp 1024, wrapped
+    (3, 1276, 8, 2, 80, 2, 1, 1275),   # danube-decode's ring, full
 ])
-def test_decode_attention_sweep(B, W, H, K, d, dtype):
+def test_decode_attention_sweep(B, W, H, K, d, L, layer, index, dtype):
+    """The stacked ring cache (L,B,K,d,Wp): position t of the sequence
+    sits in slot t % W of layer `layer`, and the token at `index` is
+    written into its slot and attended to; slots the mask leaves out (past
+    the ring, not yet written, other layers) hold garbage."""
+    Wp = cache_width(W)
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, 1, H, d), dtype)
-    k = jax.random.normal(ks[1], (B, W, K, d), dtype)
-    v = jax.random.normal(ks[2], (B, W, K, d), dtype)
-    valid = jax.random.bernoulli(ks[3], 0.8, (B, W))
-    bias = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)
-    o = decode_attention(q, k, v, bias)
-    ref = decode_attention_ref(q, k, v, bias)
+    k = 50.0 * jax.random.normal(ks[1], (L, B, K, d, Wp), dtype)
+    v = 50.0 * jax.random.normal(ks[2], (L, B, K, d, Wp), dtype)
+    seen = np.arange(max(0, index - W + 1), index + 1)     # positions kept
+    ring = jax.random.normal(ks[3], (2, B, K, d, len(seen)), dtype)
+    k = k.at[layer, ..., seen[:-1] % W].set(jnp.moveaxis(ring[0, ..., :-1],
+                                                         -1, 0))
+    v = v.at[layer, ..., seen[:-1] % W].set(jnp.moveaxis(ring[1, ..., :-1],
+                                                         -1, 0))
+    new_k, new_v = (r[..., -1][:, None] for r in ring)     # (B,1,K,d)
+    slots = np.arange(Wp)
+    pos = index - (index - slots) % W
+    bias = jnp.broadcast_to(jnp.where((slots < W) & (pos >= 0), 0.0, -1e30),
+                            (B, Wp)).astype(jnp.float32)
+    args = (q, new_k, new_v, k, v, bias, jnp.int32(layer),
+            jnp.int32(index % W))
+    o, ck, cv = decode_attention(*args)
+    ref, rk, rv = decode_attention_ref(*args)
+    # the caches: the token's slot of that layer written, nothing else
+    for c, new, old in ((ck, new_k, k), (cv, new_v, v)):
+        want = old.at[layer, ..., index % W].set(new[:, 0])
+        np.testing.assert_array_equal(np.asarray(c), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(rk), np.asarray(ck))
+    np.testing.assert_array_equal(np.asarray(rv), np.asarray(cv))
+    # the plain attention of the query over the kept positions
+    qf = np.asarray(q, np.float32).reshape(B, K, H // K, d)
+    kf, vf = np.asarray(ring, np.float32)
+    s = np.einsum("bkgd,bkdt->bkgt", qf, kf) / np.sqrt(d)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    plain = np.einsum("bkgt,bkdt->bkgd", p / p.sum(-1, keepdims=True), vf)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
         np.asarray(o, np.float32), np.asarray(ref, np.float32), atol=tol)
+    np.testing.assert_allclose(
+        np.asarray(o, np.float32).reshape(plain.shape), plain,
+        atol=tol if dtype == jnp.float32 else 5e-2)
 
 
 @pytest.mark.parametrize("B,H,S,d", [

@@ -92,7 +92,8 @@ def test_swa_ring_cache_decode():
                               cfg.vocab_size)
     full, _, _ = M.forward(params, cfg, toks, remat=False)
     caches = M.init_caches(cfg, B, S, tp=1)   # W = window = 8 ring
-    assert caches["layers"][0]["kv"]["k"].shape[2] == 8
+    # (n_periods, B, K, hd, Wp): the ring's 8 slots padded to 128 lanes
+    assert caches["layers"][0]["kv"]["k"].shape[1:] == (B, 2, 16, 128)
     _, _, caches = M.forward(params, cfg, toks[:, :S - 4], caches=caches,
                              remat=False)
     errs = []

@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.configs import ALL_ARCHS, SHAPES, get_config, supports_shape
+from repro.configs import ALL_ARCHS, SHAPES, get_config, smoke_variant, \
+    supports_shape
 from repro.models import model as M
 from repro.optim import OptimizerConfig
 from repro.sharding.rules import ShardingRules, param_specs, state_specs
@@ -64,6 +65,23 @@ def test_cache_specs_divide(arch, shape):
     rules = ShardingRules(SINGLE, seq_sharded=(sh.global_batch < 16))
     specs = rules.cache_specs(caches)
     _check_divisible(caches, specs, SINGLE)
+
+
+@pytest.mark.parametrize("seq_sharded", [False, True])
+def test_cache_specs_follow_the_kernel_layout(seq_sharded):
+    """The self-attention ring (n_periods, B, K, hd, Wp) shards its slots,
+    the last axis, where the sequence is sharded; the cross-attention
+    source (n_periods, B, T, K, hd) keeps its own order."""
+    mesh = AbstractMesh((2, 2), ("data", "model"))
+    cfg = smoke_variant(get_config("whisper-large-v3"))
+    caches = jax.eval_shape(lambda: M.init_caches(cfg, 4, 256, tp=2))
+    specs = ShardingRules(mesh, seq_sharded=seq_sharded).cache_specs(caches)
+    B, S = (None, "data") if seq_sharded else ("data", None)
+    layer = specs["layers"][0]
+    assert layer["kv"]["k"] == layer["kv"]["v"] == P(None, B, "model", None,
+                                                     S)
+    assert layer["xkv"]["k"] == layer["xkv"]["v"] == P(None, B, S, "model",
+                                                       None)
 
 
 def test_tp_weight_sharding_covers_big_tensors():
