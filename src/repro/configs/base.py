@@ -51,8 +51,17 @@ class MoEConfig:
     aux_loss_weight: float = 0.01
     # 'einsum': GShard one-hot dispatch (dense, MXU-friendly, O(N*E*C*D));
     # 'scatter': scatter/gather dispatch (O(N*K*D) data movement) — the
-    # compute-term optimization for very large E (see EXPERIMENTS §Perf)
+    # compute-term optimization for very large E (see EXPERIMENTS §Perf);
+    # 'grouped': dropless — each assignment to a held expert is computed by
+    # a grouped matmul (`lax.ragged_dot`), with no capacity
     dispatch: str = "einsum"
+    renormalize: bool = True      # top-k gates rescaled to sum to 1
+    # the experts this layer holds, [first, stop) of the num_experts the
+    # router scores (expert parallelism's share; 'grouped' only); None: all
+    held: Optional[Tuple[int, int]] = None
+
+    def held_range(self) -> Tuple[int, int]:
+        return self.held or (0, self.num_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +85,7 @@ class ModelConfig:
 
     # attention flavour
     qk_norm: bool = False
+    use_rope: bool = True         # rotary positions on self-attention
     rope_theta: float = 1e6
     sliding_window: Optional[int] = None   # SWA width (h2o-danube)
     causal: bool = True
@@ -87,8 +97,10 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     moe_every: int = 1            # apply MoE FFN every k-th layer
 
-    # hybrid (Jamba): attention every `attn_every` layers, Mamba otherwise
+    # hybrid (Jamba): attention every `attn_every` layers, at position
+    # `attn_offset` of each period, Mamba otherwise
     attn_every: int = 1
+    attn_offset: int = 0
     mamba: Optional[MambaConfig] = None
 
     # ssm (RWKV6)
@@ -176,8 +188,13 @@ class ModelConfig:
         elif self.attn_every > 1:
             m = self.mamba or MambaConfig()
             d_inner = m.expand * D
-            mamba_p = (2 * D * d_inner + d_inner * m.d_conv
-                       + d_inner * (m.d_state * 2 + 2) + d_inner * D)
+            R = math.ceil(D / 16)        # dt_rank
+            # in_proj, conv (+bias), x_proj, dt_proj (+bias), A_log, D,
+            # out_proj, and the dt, B and C norms
+            mamba_p = (2 * D * d_inner + d_inner * (m.d_conv + 1)
+                       + d_inner * (R + 2 * m.d_state) + (R + 1) * d_inner
+                       + d_inner * (m.d_state + 1) + d_inner * D
+                       + R + 2 * m.d_state)
             n_mamba = L - n_attn
             attn_total = n_attn * attn_p + n_mamba * mamba_p
             attn_active = attn_total

@@ -7,5 +7,5 @@ CONFIG = register(ModelConfig(
     num_layers=32, d_model=1280, num_heads=20, num_kv_heads=20,
     head_dim=64, d_ff=5120, vocab_size=51866,
     encoder_layers=32, num_audio_frames=1500,
-    causal=True,
+    causal=True, use_rope=False,   # absolute sinusoidal positions
 ))

@@ -282,7 +282,7 @@ def cross_attention(p, x, cfg, rules=None, *, kv=None, cache=None):
 
 
 # ---------------------------------------------------------------------------
-# FFN: SwiGLU dense + token-choice top-k MoE (GShard-style einsum dispatch)
+# FFN: SwiGLU dense + token-choice top-k MoE (einsum, scatter or grouped)
 # ---------------------------------------------------------------------------
 
 
@@ -295,14 +295,23 @@ def swiglu(p, x, rules=None):
     return jnp.einsum("bsf,fd->bsd", h, p["w_down"])
 
 
+def _moe_gates(p, xt, moe_cfg):
+    """Softmax over every expert the router scores, then the top k:
+    (gate_vals, expert_ids) (N,K), probs (N,E)."""
+    logits = jnp.einsum("nd,de->ne", xt, p["router"]).astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, expert_ids = lax.top_k(probs, moe_cfg.top_k)   # (N,K)
+    if moe_cfg.renormalize:
+        gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True),
+                                         1e-9)
+    return gate_vals, expert_ids, probs
+
+
 def _moe_route(p, xt, moe_cfg):
     """Shared routing: returns (gate_vals, expert_ids, pos, keep, probs)."""
     E, K = moe_cfg.num_experts, moe_cfg.top_k
     N = xt.shape[0]
-    logits = jnp.einsum("nd,de->ne", xt, p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = lax.top_k(probs, K)               # (N,K)
-    gate_vals = gate_vals / jnp.clip(gate_vals.sum(-1, keepdims=True), 1e-9)
+    gate_vals, expert_ids, probs = _moe_gates(p, xt, moe_cfg)
     C = max(1, int(moe_cfg.capacity_factor * K * N / E))
     # position of each (token, k) within its expert, in (k-major, token) order
     onehot = jax.nn.one_hot(expert_ids, E, dtype=jnp.int32)   # (N,K,E)
@@ -333,21 +342,91 @@ def _expert_ffn(p, xe, rules=None):
     return ye
 
 
+# rows (token, expert) assignments of one grouped-matmul call: bounds the
+# (rows, d_ff) activations of a long prefill
+MOE_ROWS = 4096
+
+
+def _moe_grouped(p, xt, moe_cfg):
+    """Dropless top-k MoE over the held experts' share (`moe_cfg.held`).
+
+    The assignments to held experts are sorted by expert and each expert's
+    group multiplied by its weights in a grouped matmul (`lax.ragged_dot`),
+    in slices of at most MOE_ROWS rows; assignments to absent experts add
+    nothing.  Returns (out (N,D), probs, expert_ids, routed (E_held,):
+    tokens routed to each held expert)."""
+    N, D = xt.shape
+    K = moe_cfg.top_k
+    lo, hi = moe_cfg.held_range()
+    E = hi - lo
+    with jax.named_scope("route"):
+        gate_vals, expert_ids, probs = _moe_gates(p, xt, moe_cfg)
+        local = expert_ids.reshape(-1) - lo                   # (N*K,)
+        held = (local >= 0) & (local < E)
+        group = jnp.where(held, local, E)                     # absent last
+        order = jnp.argsort(group, stable=True)
+        routed = jnp.sum(jax.nn.one_hot(group, E, dtype=jnp.int32), 0)
+        ends = jnp.cumsum(routed)
+        starts = ends - routed
+    M = N * K
+    c = min(MOE_ROWS, M)
+    n = -(-M // c)
+    with jax.named_scope("experts"):
+        tok = jnp.pad(order // K, (0, n * c - M))
+        def rows(j):
+            """Assignments j*c .. j*c+c-1 in expert order: the group sizes
+            are each expert's rows among them."""
+            r0 = j * c
+            sizes = jnp.clip(jnp.minimum(ends, r0 + c)
+                             - jnp.maximum(starts, r0), 0)
+            xs = jnp.take(xt, lax.dynamic_slice_in_dim(tok, r0, c), axis=0)
+            g = lax.ragged_dot(xs, p["w_gate"], sizes)
+            u = lax.ragged_dot(xs, p["w_up"], sizes)
+            h = (jax.nn.silu(g) * u).astype(xt.dtype)
+            return lax.ragged_dot(h, p["w_down"], sizes)      # (c, D)
+        y = rows(0) if n == 1 else lax.map(rows, jnp.arange(n)).reshape(
+            n * c, D)
+    with jax.named_scope("combine"):
+        # rows past the held groups (absent experts, padding) are not
+        # computed: zero them, then put each assignment back in place
+        kept = jnp.arange(n * c) < ends[-1]
+        y = jnp.where(kept[:, None], y, 0)[:M]
+        inv = jnp.zeros((M,), order.dtype).at[order].set(
+            jnp.arange(M, dtype=order.dtype))
+        w = jnp.where(held, gate_vals.reshape(-1), 0.0)
+        out = jnp.sum(y[inv].reshape(N, K, D).astype(jnp.float32)
+                      * w.reshape(N, K, 1), axis=1).astype(xt.dtype)
+    return out, probs, expert_ids, routed
+
+
 def moe_ffn(p, x, moe_cfg, rules=None):
     """Token-choice top-k MoE.
 
-    p: {router (D,E), w_gate/w_up (E,D,F), w_down (E,F,D),
-        [shared: swiglu params]}
-    Returns (out, aux_loss).  Dispatch per moe_cfg.dispatch:
+    p: {router (D,E), w_gate/w_up (E',D,F), w_down (E',F,D),
+        [shared: swiglu params]}, E' the held experts ('grouped'; else E).
+    Returns (out, aux_loss, routed): routed (E',) counts the tokens routed
+    to each held expert.  Dispatch per moe_cfg.dispatch:
       'einsum'  — GShard one-hot einsums (dense): 2*N*E*C*D dispatch flops.
       'scatter' — scatter-add to expert slots / gather back: O(N*K*D) data
                   movement, no dispatch matmuls (for very large E).
+      'grouped' — dropless, the held share only (`_moe_grouped`); names
+                  its work `moe/route`, `moe/experts`, `moe/combine`.
     """
     B, S, D = x.shape
     E, K = moe_cfg.num_experts, moe_cfg.top_k
     N = B * S
     xt = x.reshape(N, D)
+    if moe_cfg.dispatch == "grouped":
+        with jax.named_scope("moe"):
+            out, probs, expert_ids, routed = _moe_grouped(p, xt, moe_cfg)
+        out = out.reshape(B, S, D)
+        if "shared" in p:
+            out = out + swiglu(p["shared"], x, rules)
+        return out, _moe_aux(expert_ids, probs, moe_cfg), routed
+    if moe_cfg.held is not None:
+        raise ValueError("an expert share needs dispatch='grouped'")
     gate_vals, expert_ids, pos, C, probs = _moe_route(p, xt, moe_cfg)
+    routed = jnp.sum(jax.nn.one_hot(expert_ids, E, dtype=jnp.int32), (0, 1))
 
     if moe_cfg.dispatch == "scatter":
         slot = expert_ids * C + pos                           # (N,K)
@@ -385,88 +464,123 @@ def moe_ffn(p, x, moe_cfg, rules=None):
     out = out.reshape(B, S, D)
     if "shared" in p:
         out = out + swiglu(p["shared"], x, rules)
-    return out, _moe_aux(expert_ids, probs, moe_cfg)
+    return out, _moe_aux(expert_ids, probs, moe_cfg), routed
 
 
 # ---------------------------------------------------------------------------
-# Mamba (selective SSM) — chunked associative-scan, decode single-step
+# Mamba (selective SSM) — chunked associative scan, bounded working set
 # ---------------------------------------------------------------------------
 
-MAMBA_CHUNK = 256
+# tokens per chunk of the selective scan: its working set is a few
+# (B, MAMBA_CHUNK, I, N) float32 tensors
+MAMBA_CHUNK = 16
 
 
-def _mamba_ssm_chunked(dt, A, Bm, Cm, xin, h0):
+def _comb(e1, e2):
+    a1, b1 = e1
+    a2, b2 = e2
+    return a2 * a1, a2 * b1 + b2
+
+
+def _ssm_chunk(h, dt, A, Bm, Cm, xin):
+    """The recurrence over one chunk of c tokens from state h (B,I,N):
+    dt, xin (B,c,I); Bm, Cm (B,c,N), any float dtype (upcast here).
+    Returns (h after the chunk, y (B,c,I) float32)."""
+    f32 = jnp.float32
+    dt, xin = dt.astype(f32), xin.astype(f32)
+    dA = jnp.exp(dt[..., None] * A)                          # (B,c,I,N)
+    dBx = (dt * xin)[..., None] * Bm.astype(f32)[:, :, None, :]
+    aa, bb = lax.associative_scan(_comb, (dA, dBx), axis=1)
+    h_all = aa * h[:, None] + bb                              # (B,c,I,N)
+    y = jnp.einsum("bcin,bcn->bci", h_all, Cm.astype(f32))
+    return h_all[:, -1], y
+
+
+def _mamba_ssm_chunked(dt, A, Bm, Cm, xin, h0, chunk=MAMBA_CHUNK):
     """h_t = exp(dt_t*A) h_{t-1} + dt_t*B_t*x_t ; y_t = C_t . h_t.
 
-    dt,xin: (B,S,I)  Bm,Cm: (B,S,Nst)  A: (I,Nst)  h0: (B,I,Nst)
-    Returns y (B,S,I), h_final.
+    dt,xin: (B,S,I)  Bm,Cm: (B,S,Nst)  A: (I,Nst) f32  h0: (B,I,Nst) f32.
+    A loop over chunks of `chunk` tokens (an associative scan inside each),
+    then the last S % chunk tokens, read from the inputs in place: no
+    (B,S,I,N) tensor is formed.  One token (decode) is one chunk.
+    Returns y (B,S,I) in xin's dtype, h_final (B,I,Nst) f32.
     """
     Bsz, S, I = xin.shape
-    Nst = A.shape[1]
-    nchunk = max(1, S // MAMBA_CHUNK)
-    c = S // nchunk
-    dA = jnp.exp(dt[..., None] * A)                          # (B,S,I,N)
-    dBx = (dt * xin)[..., None] * Bm[:, :, None, :]          # (B,S,I,N)
+    c = min(chunk, S)
+    n, tail = divmod(S, c)
+    args = (dt, Bm, Cm, xin)
 
-    def chunk_step(h, inp):
-        dA_c, dBx_c, C_c = inp                               # (B,c,I,N),(B,c,N)
-        def comb(e1, e2):
-            a1, b1 = e1
-            a2, b2 = e2
-            return a2 * a1, a2 * b1 + b2
-        aa, bb = lax.associative_scan(comb, (dA_c, dBx_c), axis=1)
-        h_all = aa * h[:, None] + bb                          # (B,c,I,N)
-        y = jnp.einsum("bcin,bcn->bci", h_all, C_c)
-        return h_all[:, -1], y
+    def sl(t, t0, size):
+        return lax.dynamic_slice_in_dim(t, t0, size, axis=1)
 
-    dA_s = dA.reshape(Bsz, nchunk, c, I, Nst).swapaxes(0, 1)
-    dBx_s = dBx.reshape(Bsz, nchunk, c, I, Nst).swapaxes(0, 1)
-    C_s = Cm.reshape(Bsz, nchunk, c, Nst).swapaxes(0, 1)
-    h_last, ys = lax.scan(chunk_step, h0, (dA_s, dBx_s, C_s))
-    y = ys.swapaxes(0, 1).reshape(Bsz, S, I)
-    return y, h_last
+    def body(j, carry):
+        h, y = carry
+        dt_c, B_c, C_c, x_c = (sl(t, j * c, c) for t in args)
+        h, y_c = _ssm_chunk(h, dt_c, A, B_c, C_c, x_c)
+        return h, lax.dynamic_update_slice_in_dim(y, y_c.astype(y.dtype),
+                                                  j * c, axis=1)
+
+    y = jnp.zeros((Bsz, S, I), xin.dtype)
+    if n == 1:
+        h, y = body(0, (h0, y))
+    else:
+        h, y = lax.fori_loop(0, n, body, (h0, y))
+    if tail:
+        dt_t, B_t, C_t, x_t = (sl(t, n * c, tail) for t in args)
+        h, y_t = _ssm_chunk(h, dt_t, A, B_t, C_t, x_t)
+        y = lax.dynamic_update_slice_in_dim(y, y_t.astype(y.dtype), n * c,
+                                            axis=1)
+    return y, h
 
 
 def mamba(p, x, cfg, rules=None, *, state=None):
-    """Mamba-1 selective SSM block.
+    """Mamba-1 selective SSM block, as Jamba's mixer (`JambaMambaMixer`):
+    RMSNorms on dt, B and C after `x_proj`, conv bias, no projection bias.
 
     p: {in_proj (D, 2I), conv_w (dc, I), conv_b (I,), x_proj (I, R+2N),
-        dt_proj (R, I), dt_bias (I,), A_log (I,N), Dskip (I,), out_proj (I,D)}
+        dt_norm (R,), b_norm, c_norm (N,), dt_proj (R, I), dt_bias (I,),
+        A_log (I,N), Dskip (I,), out_proj (I,D)}
     state: {conv: (B, dc-1, I), ssm: (B,I,N)} for decode.
+    Names its work `in_proj`, `conv`, `ssm_params`, `scan`, `out`.
     """
     m = cfg.mamba
     B, S, D = x.shape
     I = m.expand * D
-    xz = jnp.einsum("bsd,de->bse", x, p["in_proj"])
-    xin, z = xz[..., :I], xz[..., I:]
-    if rules is not None:
-        xin = rules.cs(xin, "act_bsf")
-        z = rules.cs(z, "act_bsf")
+    with jax.named_scope("in_proj"):
+        xz = jnp.einsum("bsd,de->bse", x, p["in_proj"])
+        xin, z = xz[..., :I], xz[..., I:]
+        if rules is not None:
+            xin = rules.cs(xin, "act_bsf")
+            z = rules.cs(z, "act_bsf")
     # depthwise causal conv over seq (dc taps)
     dc = m.d_conv
-    if state is not None:
-        ctx = jnp.concatenate([state["conv"], xin], axis=1)   # (B,dc-1+S,I)
-    else:
-        ctx = jnp.pad(xin, ((0, 0), (dc - 1, 0), (0, 0)))
-    conv = sum(ctx[:, i:i + S] * p["conv_w"][i] for i in range(dc))
-    xin_c = jax.nn.silu(conv + p["conv_b"])
-    new_conv = ctx[:, -(dc - 1):] if dc > 1 else ctx[:, :0]
+    with jax.named_scope("conv"):
+        if state is not None:
+            ctx = jnp.concatenate([state["conv"], xin], axis=1)  # (B,dc-1+S,I)
+        else:
+            ctx = jnp.pad(xin, ((0, 0), (dc - 1, 0), (0, 0)))
+        conv = sum(ctx[:, i:i + S] * p["conv_w"][i] for i in range(dc))
+        xin_c = jax.nn.silu(conv + p["conv_b"])
+        new_conv = ctx[:, -(dc - 1):] if dc > 1 else ctx[:, :0]
 
     R = p["dt_proj"].shape[0]
     N = m.d_state
-    dbc = jnp.einsum("bsi,ir->bsr", xin_c, p["x_proj"])
-    dt_r, Bm, Cm = dbc[..., :R], dbc[..., R:R + N], dbc[..., R + N:]
-    dt = jax.nn.softplus(jnp.einsum("bsr,ri->bsi", dt_r, p["dt_proj"])
-                         + p["dt_bias"])
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    h0 = state["ssm"] if state is not None else jnp.zeros(
-        (B, I, N), jnp.float32)
-    y, h_last = _mamba_ssm_chunked(
-        dt.astype(jnp.float32), A, Bm.astype(jnp.float32),
-        Cm.astype(jnp.float32), xin_c.astype(jnp.float32), h0)
-    y = y.astype(x.dtype) + xin_c * p["Dskip"]
-    y = y * jax.nn.silu(z)
-    out = jnp.einsum("bsi,id->bsd", y, p["out_proj"])
+    with jax.named_scope("ssm_params"):
+        dbc = jnp.einsum("bsi,ir->bsr", xin_c, p["x_proj"])
+        dt_r = rms_norm(dbc[..., :R], p["dt_norm"], cfg.norm_eps)
+        Bm = rms_norm(dbc[..., R:R + N], p["b_norm"], cfg.norm_eps)
+        Cm = rms_norm(dbc[..., R + N:], p["c_norm"], cfg.norm_eps)
+        dt = jax.nn.softplus(jnp.einsum("bsr,ri->bsi", dt_r, p["dt_proj"])
+                             + p["dt_bias"])
+        A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    with jax.named_scope("scan"):
+        h0 = state["ssm"] if state is not None else jnp.zeros(
+            (B, I, N), jnp.float32)
+        y, h_last = _mamba_ssm_chunked(dt, A, Bm, Cm, xin_c, h0)
+    with jax.named_scope("out"):
+        y = y.astype(x.dtype) + xin_c * p["Dskip"]
+        y = y * jax.nn.silu(z)
+        out = jnp.einsum("bsi,id->bsd", y, p["out_proj"])
     new_state = {"conv": new_conv, "ssm": h_last}
     return out, new_state
 

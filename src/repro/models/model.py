@@ -8,8 +8,9 @@ lowers to a compact HLO.
 Device work is named with `jax.named_scope`, which is metadata only (the
 optimized HLO is the same without it) and shows in a profiler trace as each
 op's name path: `embed`, `norm`, `attn` (inside it `qkv`, `rope`, `kv`,
-`kernel` or `core`, `out`; `models/layers.py`), `mlp`, `unembed`, and
-`sample` (`train/steps.py`).  The slicing of the scanned weights and
+`kernel` or `core`, `out`; `models/layers.py`), `mamba` (`in_proj`, `conv`,
+`ssm_params`, `scan`, `out`), `mlp` (an MoE's inside it under `moe`:
+`route`, `experts`, `combine`), `unembed`, and `sample` (`train/steps.py`).  The slicing of the scanned weights and
 caches that `lax.scan` itself emits carries no scope: a scope inside the
 body does not reach it.  The self-attention caches are not scanned: they
 ride in the scan's carry, and `attn/kv` holds every op on them.
@@ -60,7 +61,7 @@ def block_specs(cfg: ModelConfig) -> list[dict]:
             specs.append({"kind": "attn", "ffn": "dense", "cross": True})
             continue
         if cfg.attn_every > 1:
-            kind = "attn" if pos == P - 1 else "mamba"
+            kind = "attn" if pos == cfg.attn_offset else "mamba"
         elif cfg.cross_attn_every and pos == P - 1:
             kind = "xattn"
         else:
@@ -134,11 +135,14 @@ def _ffn_params(key, cfg: ModelConfig, d_ff=None):
 
 
 def _moe_params(key, cfg: ModelConfig):
+    """The router scores all `num_experts`; the layer holds the experts of
+    `held_range()`."""
     m = cfg.moe
-    D, F, E = cfg.d_model, m.d_ff, m.num_experts
+    lo, hi = m.held_range()
+    D, F, E = cfg.d_model, m.d_ff, hi - lo
     dt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(key, 5)
-    p = {"router": _dense(ks[0], (D, E), jnp.float32),
+    p = {"router": _dense(ks[0], (D, m.num_experts), jnp.float32),
          "w_gate": _dense(ks[1], (E, D, F), dt),
          "w_up": _dense(ks[2], (E, D, F), dt),
          "w_down": _dense(ks[3], (E, F, D), dt,
@@ -167,6 +171,9 @@ def _mamba_params(key, cfg: ModelConfig):
         "dt_bias": jnp.full((I,), -2.0, dt),
         "A_log": jnp.log(A),
         "Dskip": jnp.ones((I,), dt),
+        "dt_norm": _norm_init(ks[5], R),
+        "b_norm": _norm_init(ks[6], N),
+        "c_norm": _norm_init(ks[7], N),
         "out_proj": _dense(ks[4], (I, D), dt,
                            0.02 / math.sqrt(2 * cfg.num_layers)),
     }
@@ -291,7 +298,7 @@ def _apply_block(p, spec, x, cfg, rules, *, cache=None, layer=None,
     with jax.named_scope("norm"):
         h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind == "attn":
-        use_rope = not cfg.encoder_layers     # whisper: abs pos, no rope
+        use_rope = cfg.use_rope
         with jax.named_scope("attn"):
             if mode == "decode":
                 o, kvc = L.decode_attention(p["attn"], h, cfg, rules,
@@ -332,7 +339,8 @@ def _apply_block(p, spec, x, cfg, rules, *, cache=None, layer=None,
                 new_cache = {**cache, "xkv": xc}
     elif kind == "mamba":
         st = cache.get("mamba") if cache is not None else None
-        o, mst = L.mamba(p["mamba"], h, cfg, rules, state=st)
+        with jax.named_scope("mamba"):
+            o, mst = L.mamba(p["mamba"], h, cfg, rules, state=st)
         if cache is not None:
             new_cache = {**cache, "mamba": mst}
     x = x + o
@@ -340,10 +348,25 @@ def _apply_block(p, spec, x, cfg, rules, *, cache=None, layer=None,
         h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     with jax.named_scope("mlp"):
         if spec["ffn"] == "moe":
-            o, aux = L.moe_ffn(p["moe"], h, cfg.moe, rules)
+            o, aux, routed = L.moe_ffn(p["moe"], h, cfg.moe, rules)
+            if cache is not None:
+                new_cache = {**new_cache, "moe": _counted(
+                    cache["moe"], routed, mode)}
         else:
             o = L.swiglu(p["ffn"], h, rules)
     return x + o, new_cache, aux
+
+
+def _counted(counts, routed, mode):
+    """The MoE counter (E_held, 3) int32 after one call that routed
+    `routed` (E_held,) tokens to each held expert: its columns are tokens
+    routed in prefill, tokens routed in decode, and decode steps in which
+    the expert got a token.  Prefill sets it anew; each decode step adds."""
+    zero = jnp.zeros_like(routed)
+    if mode == "decode":
+        return counts + jnp.stack(
+            [zero, routed, (routed > 0).astype(routed.dtype)], -1)
+    return jnp.stack([routed, zero, zero], -1)
 
 
 def _sinusoid(T, D):
@@ -517,6 +540,8 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int, tp: int = 1,
                 dtype=jnp.bfloat16, cross_len: Optional[int] = None) -> Pytree:
     """Zeroed caches for `batch` rows of up to `max_len` positions.
 
+    A Mamba layer keeps its conv inputs and its ssm state; an MoE layer a
+    counter of the tokens routed to each expert it holds (`_counted`).
     A self-attention cache `kv` is a ring of min(max_len, window) slots,
     held as (n_periods, batch, Kp, hd, Wp): the slots on the minor axis,
     padded to the decode kernel's `cache_width`; slots past the ring are
@@ -558,6 +583,9 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int, tp: int = 1,
                         "wkv": jnp.zeros((n_periods, batch, H, hd, hd),
                                          jnp.float32)},
                  "cm": jnp.zeros((n_periods, batch, 1, cfg.d_model), dtype)}
+        if spec.get("ffn") == "moe":    # the routing counter (`_counted`)
+            lo, hi = cfg.moe.held_range()
+            c["moe"] = jnp.zeros((n_periods, hi - lo, 3), jnp.int32)
         return c
 
     return {"index": jnp.zeros((), jnp.int32),

@@ -46,6 +46,8 @@ def test_chunked_attention_grads():
 def test_scatter_moe_matches_einsum():
     for arch in ("kimi-k2-1t-a32b", "jamba-v0.1-52b"):
         cfg = smoke_variant(get_config(arch))
+        cfg = dataclasses.replace(   # jamba's own dispatch is 'grouped'
+            cfg, moe=dataclasses.replace(cfg.moe, dispatch="einsum"))
         toks = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
                                   cfg.vocab_size)
         params = M.init_params(jax.random.PRNGKey(0), cfg, tp=1)
