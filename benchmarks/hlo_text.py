@@ -22,6 +22,7 @@ from __future__ import annotations
 import argparse
 import base64
 import hashlib
+import importlib
 import os
 import pathlib
 import re
@@ -33,11 +34,16 @@ _TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
 
 def _kernel_text(m) -> str:
     """A kernel's serialized Mosaic module -> SHA-1 of its text without
-    source locations."""
+    source locations.  A module that the compiler itself wrote (XLA's
+    own kernels, such as the ragged dot's) comes as text, not bytecode."""
     from jax._src.lib.mlir import ir
+    body = base64.b64decode(m.group(2))
+    if not body.startswith(b"ML\xefR"):
+        text = re.sub(r"\s*loc\([^)]*\)", "", body.decode())
+        return m.group(1) + hashlib.sha1(text.encode()).hexdigest()
     with ir.Context() as ctx:
         ctx.allow_unregistered_dialects = True
-        module = ir.Module.parse(base64.b64decode(m.group(2)))
+        module = ir.Module.parse(body)
         text = module.operation.get_asm(enable_debug_info=False)
     return m.group(1) + hashlib.sha1(text.encode()).hexdigest()
 
@@ -81,12 +87,15 @@ def main(argv=None) -> int:
     from jax.sharding import SingleDeviceSharding
 
     import run
-    from repro.kernels.decode_attention import ops as dops
-    from repro.kernels.flash_attention import ops as fops
     from repro.models import model as M
     from repro.train.steps import make_prefill, make_serve_step
-    # the wrappers would interpret the kernels on the CPU backend
-    for mod in (dops, fops):
+    # the wrappers would interpret the kernels on the CPU backend; a tree
+    # may lack a kernel that a later one has
+    for name in ("decode_attention", "flash_attention", "selective_scan"):
+        try:
+            mod = importlib.import_module(f"repro.kernels.{name}.ops")
+        except ModuleNotFoundError:
+            continue
         mod.interpret_mode = lambda i=None: False if i is None else i
     jax.config.update("jax_enable_compilation_cache", False)
 
@@ -96,12 +105,13 @@ def main(argv=None) -> int:
     c = run.load_json(ROOT / conf["file"])
     traffic = run.load_json(ROOT / "benchmarks" / "chip" / "traffic"
                             / f"{cell['traffic']}.json")
-    bm = run.load_module(ROOT / "benchmarks" / "chip" / "model.py",
-                         "bench_model")
     kind = run.load_module(ROOT / "benchmarks" / "chip" / "kinds"
                            / f"{traffic['kind']}.py", "kind")
+    # a hybrid kind runs serve_static's loop over a model of its own
+    bm = kind.hybrid.model if hasattr(kind, "hybrid") else run.load_module(
+        ROOT / "benchmarks" / "chip" / "model.py", "bench_model")
     B, P = traffic["batch"], traffic["prompt_len"]
-    G = int(kind.answer_lengths(traffic).max())
+    G = int(getattr(kind, "static", kind).answer_lengths(traffic).max())
     cfg = bm.program_config(c)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")

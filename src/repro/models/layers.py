@@ -8,8 +8,8 @@ Conventions
 * `rules` is an optional `ShardingRules`; `rules.cs(x, logical)` applies a
   with_sharding_constraint, or is a no-op on a single device.
 * Layers are written with jnp/lax only (scan/associative_scan for SSMs) so
-  they lower under GSPMD; attention can be swapped for the Pallas kernel
-  with cfg.use_pallas (TPU).
+  they lower under GSPMD; with `use_pallas` (TPU), attention and the Mamba
+  prefill scan run Pallas kernels instead.
 * Attention names its device work with `jax.named_scope` (metadata only):
   `qkv`, `rope`, `kv` (prefill's cache fill; the flash wrapper's layout),
   `kernel` (the Pallas path; the flash wrapper adds `kv` and `kernel`
@@ -533,9 +533,12 @@ def _mamba_ssm_chunked(dt, A, Bm, Cm, xin, h0, chunk=MAMBA_CHUNK):
     return y, h
 
 
-def mamba(p, x, cfg, rules=None, *, state=None):
+def mamba(p, x, cfg, rules=None, *, state=None, use_pallas=False):
     """Mamba-1 selective SSM block, as Jamba's mixer (`JambaMambaMixer`):
     RMSNorms on dt, B and C after `x_proj`, conv bias, no projection bias.
+    With `use_pallas`, a sequence of more than one token runs the scan in
+    the Pallas kernel (`kernels/selective_scan`); one token (decode) and
+    the XLA path run `_mamba_ssm_chunked`.
 
     p: {in_proj (D, 2I), conv_w (dc, I), conv_b (I,), x_proj (I, R+2N),
         dt_norm (R,), b_norm, c_norm (N,), dt_proj (R, I), dt_bias (I,),
@@ -576,7 +579,11 @@ def mamba(p, x, cfg, rules=None, *, state=None):
     with jax.named_scope("scan"):
         h0 = state["ssm"] if state is not None else jnp.zeros(
             (B, I, N), jnp.float32)
-        y, h_last = _mamba_ssm_chunked(dt, A, Bm, Cm, xin_c, h0)
+        if use_pallas and S > 1:
+            from repro.kernels.selective_scan import selective_scan
+            y, h_last = selective_scan(dt, A, Bm, Cm, xin_c, h0)
+        else:
+            y, h_last = _mamba_ssm_chunked(dt, A, Bm, Cm, xin_c, h0)
     with jax.named_scope("out"):
         y = y.astype(x.dtype) + xin_c * p["Dskip"]
         y = y * jax.nn.silu(z)

@@ -340,7 +340,8 @@ def _apply_block(p, spec, x, cfg, rules, *, cache=None, layer=None,
     elif kind == "mamba":
         st = cache.get("mamba") if cache is not None else None
         with jax.named_scope("mamba"):
-            o, mst = L.mamba(p["mamba"], h, cfg, rules, state=st)
+            o, mst = L.mamba(p["mamba"], h, cfg, rules, state=st,
+                             use_pallas=use_pallas)
         if cache is not None:
             new_cache = {**cache, "mamba": mst}
     x = x + o

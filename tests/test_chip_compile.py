@@ -30,6 +30,8 @@ from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.rwkv6.rwkv6 import wkv6_fwd
+from repro.kernels.selective_scan import ops as scan_ops
+from repro.kernels.selective_scan.ops import selective_scan
 from repro.models import model as M
 from repro.train.steps import make_prefill, make_serve_step
 
@@ -98,6 +100,19 @@ def test_decode_attention_compiles_batch_8(one_chip):
     txt = jax.jit(functools.partial(decode_attention, interpret=False),
                   donate_argnums=(3, 4)).lower(
         q, new, new, kv, kv, bias, index, index).compile().as_text()
+    assert KERNEL in txt
+
+
+def test_selective_scan_compiles_jamba_chat(one_chip):
+    # jamba-chat's prefill: B 8, prompt 1020 (a partial last block),
+    # I = 2 x 4096, N 16; dt, x and y in bf16
+    B, S, I, N = 8, 1020, 8192, 16
+    x = _sds((B, S, I), one_chip)
+    bc = _sds((B, S, N), one_chip)
+    a = _sds((I, N), one_chip, jnp.float32)
+    h = _sds((B, I, N), one_chip, jnp.float32)
+    txt = jax.jit(functools.partial(selective_scan, interpret=False)).lower(
+        x, a, bc, bc, x, h).compile().as_text()
     assert KERNEL in txt
 
 
@@ -174,7 +189,7 @@ def compiled_kernels(monkeypatch):
     """Programs traced here compile the kernels: the wrappers would pick
     interpretation from the default backend, the CPU.  Traced programs are
     cached by shape, not by that choice, so the caches are cleared around."""
-    for mod in (decode_ops, flash_ops):
+    for mod in (decode_ops, flash_ops, scan_ops):
         monkeypatch.setattr(mod, "interpret_mode",
                             lambda i=None: False if i is None else i)
     jax.clear_caches()
@@ -205,6 +220,29 @@ def test_serving_programs_keep_scopes_compiled(one_chip, compiled_kernels):
                                                        len(writes))
         assert all("/attn/kv/" in ln or "/attn/jit" in ln and "/kv/" in ln
                    for ln in pads + writes)
+
+
+JAMBA_PERIOD = dataclasses.replace(
+    get_config("jamba-v0.1-52b"), num_layers=8, d_model=1024, d_ff=1024,
+    vocab_size=1024, moe=dataclasses.replace(
+        get_config("jamba-v0.1-52b").moe, num_experts=4, d_ff=512))
+
+
+def test_mamba_prefill_runs_the_scan_kernel(one_chip, compiled_kernels):
+    """One Jamba period (7 Mamba mixers, I 2048, N 16), prefill with the
+    Pallas kernels: each mixer's scan is one `selective_scan` kernel under
+    `mamba/scan`, and no (B, chunk, I, N) tensor of the chunked associative
+    scan is padded there; the decode step keeps the one-token update."""
+    prefill, step = serving_programs(JAMBA_PERIOD, one_chip, use_pallas=True)
+    lines = prefill.compile().as_text().splitlines()
+    calls = [ln for ln in lines if "custom_call_target=" in ln
+             and re.match(r"\s*%selective_scan\.\d+ = ", ln)]
+    assert len(calls) == 7 and all(KERNEL in ln and "/mamba/scan/" in ln
+                                   for ln in calls)
+    pads = [ln for ln in lines if "/mamba/scan/" in ln
+            and re.search(r"= f32\[\d+,\d+,\d+,\d+\]\S* pad\(", ln)]
+    assert pads == []
+    assert "selective_scan" not in step.compile().as_text()
 
 
 def _instructions(hlo: str):

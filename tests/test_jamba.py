@@ -273,6 +273,50 @@ def test_bounded_scan_is_the_per_token_recurrence(S_, chunk):
                                atol=1e-5)
 
 
+def test_prefill_kernel_is_the_xla_scan(weights):
+    """Prefill through the Pallas kernels (interpreted) equals prefill
+    through the chunked scan and XLA attention, to float32 rounding through
+    8 layers: logits, and each Mamba layer's conv and ssm state, at a prompt
+    of one whole block and a partial one."""
+    toks = jax.random.randint(jax.random.PRNGKey(6), (B, 140), 0,
+                              TINY.vocab_size)
+    out = []
+    for use_pallas in (True, False):
+        caches = M.init_caches(TINY32, B, 144, dtype=jnp.float32)
+        logits, _, caches = M.forward(weights, TINY32, toks, caches=caches,
+                                      use_pallas=use_pallas, remat=False)
+        out.append((logits, [c["mamba"] for c in caches["layers"]
+                             if "mamba" in c]))
+    (lk, mk), (lx, mx) = out
+    assert len(mk) == 7
+    assert _err(lk, np.asarray(lx, np.float32)) < TOL
+    for a, b in zip(mk, mx):
+        for k in ("conv", "ssm"):
+            np.testing.assert_allclose(np.asarray(a[k]), np.asarray(b[k]),
+                                       rtol=1e-5, atol=1e-5)
+
+
+def test_kernel_scan_gradient_is_the_xla_scans(weights, tokens):
+    """A training loss differentiates through the kernel's custom_vjp: its
+    gradient with `use_pallas=True` is the XLA path's."""
+    targets = jnp.roll(tokens, -1, axis=1)
+
+    def loss(params, use_pallas):
+        logits, _, _ = M.forward(params, TINY32, tokens,
+                                 use_pallas=use_pallas)
+        logp = jax.nn.log_softmax(logits[..., :TINY.vocab_size], -1)
+        return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], -1))
+    grads = [jax.jit(jax.grad(loss), static_argnums=1)(weights, p)
+             for p in (True, False)]
+    flat_k, flat_x = (jax.tree_util.tree_leaves_with_path(g) for g in grads)
+    mamba = [k for k, _ in flat_k if "mamba" in jax.tree_util.keystr(k)]
+    assert mamba
+    for (path, a), (_, b) in zip(flat_k, flat_x):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5, err_msg=jax.tree_util.keystr(
+                                       path))
+
+
 def _hf_model(params, cfg):
     """`transformers`' JambaForCausalLM at `cfg`'s sizes, float32, holding
     `params` (the program's layout) copied in."""

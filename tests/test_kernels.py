@@ -11,6 +11,10 @@ from repro.kernels.decode_attention import cache_width, decode_attention, \
 from repro.kernels.flash_attention import flash_attention, \
     flash_attention_ref
 from repro.kernels.rwkv6 import wkv6, wkv6_ref
+from repro.kernels.selective_scan import selective_scan, selective_scan_ref
+from repro.kernels.selective_scan.selective_scan import DEFAULT_BI, \
+    DEFAULT_BS
+from repro.models import jamba_ref
 
 KEY = jax.random.PRNGKey(0)
 
@@ -121,6 +125,47 @@ def test_wkv6_sweep(B, H, S, d):
     oref, sref = wkv6_ref(r, k, v, logw, u, jnp.zeros((B, H, d, d)))
     np.testing.assert_allclose(np.asarray(o), np.asarray(oref), atol=1e-4)
     np.testing.assert_allclose(np.asarray(sf), np.asarray(sref), atol=1e-4)
+
+
+def _scan_inputs(n, S, I, N, dtype, h0_scale):
+    ks = jax.random.split(jax.random.PRNGKey(S), 6)
+    dt = jax.nn.softplus(jax.random.normal(ks[0], (n, S, I)) - 1.0)
+    A = -jnp.exp(jax.random.normal(ks[1], (I, N)))
+    Bm, Cm = (jax.random.normal(k, (n, S, N)) for k in ks[2:4])
+    x = jax.random.normal(ks[4], (n, S, I))
+    h0 = h0_scale * jax.random.normal(ks[5], (n, I, N))
+    dt, Bm, Cm, x = (t.astype(dtype) for t in (dt, Bm, Cm, x))
+    return dt, A, Bm, Cm, x, h0
+
+
+@pytest.mark.parametrize("S,I,N,dtype,h0_scale", [
+    (2 * DEFAULT_BS, 256, 16, jnp.float32, 0.0),     # whole blocks
+    (DEFAULT_BS + 72, 128, 8, jnp.float32, 0.0),     # a partial last block
+    (37, 200, 16, jnp.float32, 0.0),                 # less than a block
+    (1, 128, 16, jnp.float32, 0.0),                  # one token
+    (DEFAULT_BS + 72, 128, 16, jnp.float32, 1.0),    # a state carried in
+    (DEFAULT_BS + 72, 256, 16, jnp.bfloat16, 1.0),   # served dtype
+    (DEFAULT_BS, DEFAULT_BI + 128, 16, jnp.float32, 1.0),  # channel tiles
+])
+def test_selective_scan_sweep(S, I, N, dtype, h0_scale):
+    """The kernel against the per-token recurrence of the float32
+    reference and against the model's chunked scan: the state to 1e-5;
+    y to 1e-5 in float32, and in bfloat16 within one rounding of the
+    float32 result (the kernel computes in float32 whatever comes in)."""
+    args = _scan_inputs(2, S, I, N, dtype, h0_scale)
+    y, h = selective_scan(*args)
+    f32 = [t.astype(jnp.float32) for t in args]
+    with jax.default_matmul_precision("highest"):
+        y_ref, h_ref = jamba_ref.selective_scan(*f32)
+        y_xla, h_xla = selective_scan_ref(*args)
+    assert y.dtype == y_xla.dtype == dtype and y.shape == (2, S, I)
+    for other in (h_ref, h_xla):
+        np.testing.assert_allclose(np.asarray(h), np.asarray(other),
+                                   rtol=1e-5, atol=1e-5)
+    y, y_xla = (np.asarray(t, np.float32) for t in (y, y_xla))
+    rtol = 1e-5 if dtype == jnp.float32 else 2.0 ** -8
+    np.testing.assert_allclose(y, np.asarray(y_ref), rtol=rtol, atol=1e-5)
+    np.testing.assert_allclose(y, y_xla, rtol=2 * rtol, atol=1e-5)
 
 
 def test_wkv6_strong_decay_stability():
