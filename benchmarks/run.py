@@ -17,10 +17,10 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import (bench_costmodel, bench_fig3, bench_fig4,
-                            bench_table1, bench_table2, roofline)
+                            bench_table1, bench_table2)
     print("name,us_per_call,derived")
     mods = [bench_costmodel, bench_table1, bench_fig3, bench_fig4,
-            bench_table2, roofline]
+            bench_table2]
     for mod in mods:
         for name, us, derived in mod.run():
             print(f"{name},{us:.1f},{derived}")

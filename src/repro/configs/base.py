@@ -45,20 +45,19 @@ class MoEConfig:
     num_experts: int
     top_k: int
     d_ff: int                     # per-expert hidden
-    capacity_factor: float = 1.25
     num_shared_experts: int = 0   # always-on experts (Kimi-K2 style)
-    router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
-    # 'einsum': GShard one-hot dispatch (dense, MXU-friendly, O(N*E*C*D));
-    # 'scatter': scatter/gather dispatch (O(N*K*D) data movement) — the
-    # compute-term optimization for very large E (see EXPERIMENTS §Perf);
-    # 'grouped': dropless — each assignment to a held expert is computed by
-    # a grouped matmul (`lax.ragged_dot`), with no capacity
-    dispatch: str = "einsum"
+    # goes once the benchmark stops naming it (ROADMAP Design 2)
+    dispatch: str = "grouped"
     renormalize: bool = True      # top-k gates rescaled to sum to 1
     # the experts this layer holds, [first, stop) of the num_experts the
-    # router scores (expert parallelism's share; 'grouped' only); None: all
+    # router scores (expert parallelism's share); None: all
     held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.dispatch != "grouped":
+            raise ValueError(f"MoE dispatch {self.dispatch!r}: the only "
+                             "dispatch is 'grouped' (dropless)")
 
     def held_range(self) -> Tuple[int, int]:
         return self.held or (0, self.num_experts)
@@ -89,9 +88,6 @@ class ModelConfig:
     rope_theta: float = 1e6
     sliding_window: Optional[int] = None   # SWA width (h2o-danube)
     causal: bool = True
-    # online-softmax (flash) attention over key blocks of this size; None
-    # uses the naive O(S^2)-score reference path (paper-faithful baseline)
-    attn_block: Optional[int] = None
 
     # MoE
     moe: Optional[MoEConfig] = None
@@ -129,7 +125,7 @@ class ModelConfig:
     def padded_heads(self, tp: int) -> Tuple[int, int, int]:
         """Return (q_heads', kv_heads_stored', group') after TP alignment.
 
-        Strategy (DESIGN.md §4): let G = H/K q-heads per kv group.
+        Strategy: let G = H/K q-heads per kv group.
           * K >= tp              : pad K to multiple of tp; q padded G*K'.
           * K <  tp (tp%K == 0)  : pad G to multiple of r=tp/K, store each kv
                                    head repeated r times => kv_stored = tp-
@@ -265,7 +261,8 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
 
 
 def supports_shape(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """Whether (arch, shape) is a runnable cell (DESIGN.md §4 skips)."""
+    """Whether (arch, shape) is a runnable cell: a 512k-token context is
+    skipped where every layer attends over all of it."""
     sub_quadratic = (cfg.rwkv or cfg.attn_every > 1
                      or cfg.sliding_window is not None)
     if shape.name == "long_500k" and not sub_quadratic:

@@ -15,7 +15,6 @@ CONFIG = register(ModelConfig(
     head_dim=128, d_ff=14336, vocab_size=65536, norm_eps=1e-6,
     use_rope=False, attn_every=8, attn_offset=4,
     mamba=MambaConfig(d_state=16, d_conv=4, expand=2),
-    moe=MoEConfig(num_experts=16, top_k=2, d_ff=14336, dispatch="grouped",
-                  renormalize=False),
+    moe=MoEConfig(num_experts=16, top_k=2, d_ff=14336, renormalize=False),
     moe_every=2,
 ))

@@ -16,8 +16,8 @@ attached.  Usage:
 
 Each successful cell writes artifacts/dryrun/<arch>__<shape>__<mesh>.json
 with per-device FLOPs/bytes, collective bytes by tier (ICI vs DCN), peak
-memory, and the derived roofline terms (consumed by benchmarks/roofline.py
-and EXPERIMENTS.md).
+memory, the derived roofline terms, and a `sim_trace` block that
+`repro.sim.workloads.training_from_trace` replays.
 """
 import argparse
 import json
@@ -42,7 +42,7 @@ from repro.train.steps import (
 ART = pathlib.Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
 
 # archs whose optimizer state must be int8 to have any chance of fitting
-# a 256-chip pod (DESIGN.md §5; Lovelock bounded-memory ethos)
+# a 256-chip pod (Lovelock's bounded-memory ethos)
 _INT8_STATE = {"kimi-k2-1t-a32b", "llama3-405b", "llama-3.2-vision-90b"}
 
 
@@ -63,17 +63,12 @@ def _batch_shardings(batch, rules):
 
 
 def lower_cell(arch: str, shape_name: str, mesh, *, grad_sync="gspmd",
-               remat=True, compute_dtype=None, attn_block=None,
-               cfg_overrides=None, fsdp=True, microbatches=1):
+               remat=True, compute_dtype=None):
     """Lower one cell; returns (lowered, aux_info)."""
     import dataclasses
     cfg = get_config(arch)
     if compute_dtype:
         cfg = dataclasses.replace(cfg, compute_dtype=compute_dtype)
-    if attn_block:
-        cfg = dataclasses.replace(cfg, attn_block=attn_block)
-    if cfg_overrides:
-        cfg = dataclasses.replace(cfg, **cfg_overrides)
     shape = SHAPES[shape_name]
     tp = mesh.shape["model"]
     dp = 1
@@ -98,8 +93,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, grad_sync="gspmd",
             batch = input_specs(cfg, shape)
             bshard = _batch_shardings(batch, rules)
             step = make_train_step(cfg, opt_cfg, rules, remat=remat,
-                                   grad_sync=grad_sync,
-                                   microbatches=microbatches)
+                                   grad_sync=grad_sync)
             lowered = jax.jit(step, in_shardings=(sshard, bshard),
                               out_shardings=(sshard, None)).lower(state, batch)
         elif shape.kind == "prefill":
@@ -124,7 +118,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, grad_sync="gspmd",
             params = jax.eval_shape(
                 lambda: M.init_params(jax.random.PRNGKey(0), cfg, tp))
             pshard = jax.tree.map(lambda s: NamedSharding(mesh, s),
-                                  param_specs(params, mesh, fsdp=fsdp),
+                                  param_specs(params, mesh),
                                   is_leaf=lambda x: isinstance(x, P))
             caches = abstract_caches(cfg, shape, tp)
             cshard = jax.tree.map(lambda s: NamedSharding(mesh, s),
@@ -141,27 +135,20 @@ def lower_cell(arch: str, shape_name: str, mesh, *, grad_sync="gspmd",
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
              grad_sync="gspmd", remat=True, save=True, tag="",
-             compute_dtype=None, attn_block=None,
-             cfg_overrides=None, fsdp=True, microbatches=1) -> dict:
+             compute_dtype=None) -> dict:
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     ok, why = supports_shape(cfg, shape)
     if not ok:
         return {"arch": arch, "shape": shape_name, "mesh": mesh_kind,
                 "status": "skipped", "reason": why}
-    if shape.kind == "decode" and cfg.encoder_layers == 0 and \
-            cfg.family == "audio":
-        pass
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     n_dev = mesh.size
     pod_size = (n_dev // mesh.shape["pod"]) if "pod" in mesh.axis_names \
         else None
     t0 = time.perf_counter()
     lowered, aux = lower_cell(arch, shape_name, mesh, grad_sync=grad_sync,
-                              remat=remat, compute_dtype=compute_dtype,
-                              attn_block=attn_block,
-                              cfg_overrides=cfg_overrides, fsdp=fsdp,
-                              microbatches=microbatches)
+                              remat=remat, compute_dtype=compute_dtype)
     t1 = time.perf_counter()
     compiled = lowered.compile()
     t2 = time.perf_counter()
@@ -226,7 +213,6 @@ def main():
     ap.add_argument("--grad-sync", default="gspmd")
     ap.add_argument("--tag", default="")
     ap.add_argument("--compute-dtype", default=None)
-    ap.add_argument("--attn-block", type=int, default=None)
     ap.add_argument("--no-remat", action="store_true")
     args = ap.parse_args()
 
@@ -242,7 +228,6 @@ def main():
                     rec = run_cell(arch, shape, mk,
                                    grad_sync=args.grad_sync, tag=args.tag,
                                    remat=not args.no_remat,
-                                   attn_block=args.attn_block,
                                    compute_dtype=args.compute_dtype)
                 except Exception as e:  # noqa: BLE001
                     rec = {"arch": arch, "shape": shape, "mesh": mk,

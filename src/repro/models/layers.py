@@ -16,6 +16,8 @@ Conventions
   itself; the decode kernel also writes the token's cache slot) or `core`
   (the XLA path, the decode slot write included), and `out`.  The caller
   puts them under `attn` (`models/model.py`).
+* The MoE is dropless: each token's top-k assignments to the experts the
+  layer holds run as grouped matmuls (`lax.ragged_dot`), with no capacity.
 """
 from __future__ import annotations
 
@@ -65,52 +67,6 @@ def _mask_bias(q_pos, k_pos, causal, window):
 # ---------------------------------------------------------------------------
 # Attention (GQA + RoPE + qk-norm + SWA + cross-attn)
 # ---------------------------------------------------------------------------
-
-
-def _attn_core_chunked(q, k, v, q_pos, k_pos, causal, window, block=512):
-    """Online-softmax attention scanned over key blocks (XLA-native flash).
-
-    Never materializes the (Sq, Sk) score tensor: peak activation memory
-    is O(Sq * block) instead of O(Sq * Sk) — the same insight as the
-    Pallas kernel, expressed in lax.scan so it lowers on every backend.
-    q (B,Sq,H,d), k/v (B,Sk,K,d), *_pos (B,S). fp32 accumulation.
-    """
-    B, Sq, Hq, dh = q.shape
-    Sk, Kv = k.shape[1], k.shape[2]
-    group = Hq // Kv
-    nb = max(1, Sk // block)
-    block = Sk // nb
-    assert nb * block == Sk, (Sk, block)
-    qg = (q.reshape(B, Sq, Kv, group, dh).astype(jnp.float32)
-          / math.sqrt(dh))
-    ks = k.reshape(B, nb, block, Kv, dh).swapaxes(0, 1)
-    vs = v.reshape(B, nb, block, Kv, dh).swapaxes(0, 1)
-    kps = k_pos.reshape(B, nb, block).swapaxes(0, 1)
-
-    def step(carry, inp):
-        m, l, acc = carry
-        kb, vb, kp = inp
-        s = jnp.einsum("bskgd,btkd->bkgst", qg, kb.astype(jnp.float32))
-        d = q_pos[:, None, None, :, None] - kp[:, None, None, None, :]
-        ok = d >= 0 if causal else jnp.full(d.shape, True)
-        if window is not None:
-            ok = ok & (d < window)
-        s = jnp.where(ok, s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "bkgst,btkd->bkgsd", p.astype(vb.dtype), vb).astype(jnp.float32)
-        return (m_new, l, acc), None
-
-    m0 = jnp.full((B, Kv, group, Sq), -1e30, jnp.float32)
-    l0 = jnp.zeros((B, Kv, group, Sq), jnp.float32)
-    a0 = jnp.zeros((B, Kv, group, Sq, dh), jnp.float32)
-    (m, l, acc), _ = lax.scan(step, (m0, l0, a0), (ks, vs, kps))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return (out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, Hq, dh)
-            .astype(v.dtype))
 
 
 def _attn_core(q, k, v, bias, rules=None):
@@ -192,10 +148,6 @@ def self_attention(p, x, cfg, rules=None, *, causal=None, use_rope=True,
         from repro.kernels.flash_attention import ops as fops
         o = fops.flash_attention(q, k, v, causal=causal,
                                  window=cfg.sliding_window)
-    elif getattr(cfg, "attn_block", None):
-        with jax.named_scope("core"):
-            o = _attn_core_chunked(q, k, v, positions, positions, causal,
-                                   cfg.sliding_window, block=cfg.attn_block)
     else:
         with jax.named_scope("core"):
             bias = _mask_bias(positions, positions, causal,
@@ -282,7 +234,7 @@ def cross_attention(p, x, cfg, rules=None, *, kv=None, cache=None):
 
 
 # ---------------------------------------------------------------------------
-# FFN: SwiGLU dense + token-choice top-k MoE (einsum, scatter or grouped)
+# FFN: SwiGLU dense + dropless token-choice top-k MoE (grouped matmuls)
 # ---------------------------------------------------------------------------
 
 
@@ -307,39 +259,11 @@ def _moe_gates(p, xt, moe_cfg):
     return gate_vals, expert_ids, probs
 
 
-def _moe_route(p, xt, moe_cfg):
-    """Shared routing: returns (gate_vals, expert_ids, pos, keep, probs)."""
-    E, K = moe_cfg.num_experts, moe_cfg.top_k
-    N = xt.shape[0]
-    gate_vals, expert_ids, probs = _moe_gates(p, xt, moe_cfg)
-    C = max(1, int(moe_cfg.capacity_factor * K * N / E))
-    # position of each (token, k) within its expert, in (k-major, token) order
-    onehot = jax.nn.one_hot(expert_ids, E, dtype=jnp.int32)   # (N,K,E)
-    flat = onehot.transpose(1, 0, 2).reshape(K * N, E)
-    pos_flat = jnp.cumsum(flat, axis=0) - flat                # (K*N, E)
-    pos = (pos_flat.reshape(K, N, E).transpose(1, 0, 2)
-           * onehot).sum(-1)                                  # (N,K)
-    keep = (pos < C).astype(gate_vals.dtype)
-    return gate_vals * keep, expert_ids, pos, C, probs
-
-
 def _moe_aux(expert_ids, probs, moe_cfg):
     E = moe_cfg.num_experts
     f = jnp.mean(jax.nn.one_hot(expert_ids[:, 0], E, dtype=jnp.float32), 0)
     pm = jnp.mean(probs, 0)
     return E * jnp.sum(f * pm) * moe_cfg.aux_loss_weight
-
-
-def _expert_ffn(p, xe, rules=None):
-    g = jnp.einsum("ecd,edf->ecf", xe, p["w_gate"])
-    u = jnp.einsum("ecd,edf->ecf", xe, p["w_up"])
-    h = jax.nn.silu(g) * u
-    if rules is not None:
-        h = rules.cs(h, "moe_ecf")
-    ye = jnp.einsum("ecf,efd->ecd", h, p["w_down"])
-    if rules is not None:
-        ye = rules.cs(ye, "moe_ecd")
-    return ye
 
 
 # rows (token, expert) assignments of one grouped-matmul call: bounds the
@@ -400,67 +324,19 @@ def _moe_grouped(p, xt, moe_cfg):
 
 
 def moe_ffn(p, x, moe_cfg, rules=None):
-    """Token-choice top-k MoE.
+    """Token-choice top-k MoE, dropless, over the held experts' share
+    (`_moe_grouped`); names its work `moe/route`, `moe/experts` and
+    `moe/combine`.
 
     p: {router (D,E), w_gate/w_up (E',D,F), w_down (E',F,D),
-        [shared: swiglu params]}, E' the held experts ('grouped'; else E).
+        [shared: swiglu params]}, E' the held experts (all E by default).
     Returns (out, aux_loss, routed): routed (E',) counts the tokens routed
-    to each held expert.  Dispatch per moe_cfg.dispatch:
-      'einsum'  — GShard one-hot einsums (dense): 2*N*E*C*D dispatch flops.
-      'scatter' — scatter-add to expert slots / gather back: O(N*K*D) data
-                  movement, no dispatch matmuls (for very large E).
-      'grouped' — dropless, the held share only (`_moe_grouped`); names
-                  its work `moe/route`, `moe/experts`, `moe/combine`.
+    to each held expert.
     """
     B, S, D = x.shape
-    E, K = moe_cfg.num_experts, moe_cfg.top_k
-    N = B * S
-    xt = x.reshape(N, D)
-    if moe_cfg.dispatch == "grouped":
-        with jax.named_scope("moe"):
-            out, probs, expert_ids, routed = _moe_grouped(p, xt, moe_cfg)
-        out = out.reshape(B, S, D)
-        if "shared" in p:
-            out = out + swiglu(p["shared"], x, rules)
-        return out, _moe_aux(expert_ids, probs, moe_cfg), routed
-    if moe_cfg.held is not None:
-        raise ValueError("an expert share needs dispatch='grouped'")
-    gate_vals, expert_ids, pos, C, probs = _moe_route(p, xt, moe_cfg)
-    routed = jnp.sum(jax.nn.one_hot(expert_ids, E, dtype=jnp.int32), (0, 1))
-
-    if moe_cfg.dispatch == "scatter":
-        slot = expert_ids * C + pos                           # (N,K)
-        keep = gate_vals > 0
-        slot = jnp.where(keep, slot, E * C)                   # overflow bin
-        xe = jnp.zeros((E * C + 1, D), xt.dtype)
-        xe = xe.at[slot.reshape(-1)].add(
-            jnp.repeat(xt[:, None, :], K, 1).reshape(-1, D),
-            mode="drop")
-        xe = xe[:E * C].reshape(E, C, D)
-        if rules is not None:
-            xe = rules.cs(xe, "moe_ecd")
-        ye = _expert_ffn(p, xe, rules)
-        flat = ye.reshape(E * C, D)
-        back = jnp.take(flat, jnp.clip(slot, 0, E * C - 1).reshape(-1),
-                        axis=0).reshape(N, K, D)
-        out = jnp.sum(back * gate_vals[..., None].astype(back.dtype), axis=1)
-    else:
-        # dispatch/combine tensors (N,E,C) factored per k to bound memory
-        xe = jnp.zeros((E, C, D), xt.dtype)
-        combine = jnp.zeros((N, E, C), jnp.float32)
-        for k in range(K):
-            d_k = (jax.nn.one_hot(expert_ids[:, k], E,
-                                  dtype=xt.dtype)[:, :, None]
-                   * jax.nn.one_hot(pos[:, k], C, dtype=xt.dtype)[:, None, :])
-            d_k = d_k * (gate_vals[:, k] > 0)[:, None, None].astype(xt.dtype)
-            xe = xe + jnp.einsum("nec,nd->ecd", d_k, xt)
-            combine = combine + (d_k.astype(jnp.float32)
-                                 * gate_vals[:, k, None, None])
-        if rules is not None:
-            xe = rules.cs(xe, "moe_ecd")
-        ye = _expert_ffn(p, xe, rules)
-        out = jnp.einsum("nec,ecd->nd", combine.astype(ye.dtype), ye)
-
+    xt = x.reshape(B * S, D)
+    with jax.named_scope("moe"):
+        out, probs, expert_ids, routed = _moe_grouped(p, xt, moe_cfg)
     out = out.reshape(B, S, D)
     if "shared" in p:
         out = out + swiglu(p["shared"], x, rules)

@@ -71,14 +71,11 @@ def _path_str(path) -> str:
 
 
 def param_specs(params: Pytree, mesh, *, stacked_prefix="layers/",
-                fsdp_pod: bool = True, fsdp: bool = True) -> Pytree:
-    """PartitionSpec tree for a param (or optimizer-state) tree.
-
-    fsdp=False replicates params over the batch axes (TP-only sharding) —
-    right for decode, where per-step FSDP all-gathers dominate collectives.
-    """
+                fsdp_pod: bool = True) -> Pytree:
+    """PartitionSpec tree for a param (or optimizer-state) tree, sharded
+    (FSDP) over the batch axes."""
     names = mesh.axis_names
-    cand = (("pod", "data") if fsdp_pod else ("data",)) if fsdp else ()
+    cand = ("pod", "data") if fsdp_pod else ("data",)
     b = tuple(n for n in cand if n in names)
     b = b if len(b) > 1 else (b[0] if b else None)
 
@@ -167,8 +164,6 @@ class ShardingRules:
             "act_bshd":  P(B, S, "model", None),
             "act_bsf":   P(B, S, "model"),
             "logits_bsv": P(B, S, "model"),
-            "moe_ecd":   P("model", None, None),
-            "moe_ecf":   P("model", None, None),
             "tokens":    P(B, S),
         }
 

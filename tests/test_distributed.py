@@ -128,9 +128,12 @@ print("OK", rec["roofline"]["bottleneck"])
 """)
 
 
-def test_dp_matches_single_device():
-    """Data-parallel sharded loss == single-device loss (same batch)."""
-    run_py("""
+@pytest.mark.parametrize("arch", ["qwen3-32b", "llama4-scout-17b-a16e",
+                                  "kimi-k2-1t-a32b"])
+def test_dp_matches_single_device(arch):
+    """Data-parallel sharded loss == single-device loss (same batch); the
+    MoE configs route through the grouped experts under the mesh."""
+    run_py(f"ARCH = {arch!r}\n" + """
 import jax, jax.numpy as jnp, numpy as np
 from repro.launch.mesh import auto_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -139,7 +142,7 @@ from repro.models import model as M
 from repro.optim import OptimizerConfig, adamw_init
 from repro.sharding.rules import ShardingRules, state_specs
 from repro.train.steps import make_train_step
-cfg = smoke_variant(get_config("qwen3-32b"))
+cfg = smoke_variant(get_config(ARCH))
 oc = OptimizerConfig()
 toks = jax.random.randint(jax.random.PRNGKey(1), (8, 17), 0, cfg.vocab_size)
 batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}

@@ -239,22 +239,6 @@ def test_grouped_rows_in_slices(weights, monkeypatch):
     assert (np.asarray(r1) == np.asarray(r2)).all()
 
 
-def test_grouped_matches_einsum_without_drops(weights):
-    # every expert held and a capacity no token exceeds: the dropless
-    # grouped layer and the GShard einsum layer are one function
-    e = jax.tree.map(lambda a: a[0], weights["layers"][5]["moe"])
-    x = jax.random.normal(jax.random.PRNGKey(6), (B, S, TINY.d_model))
-    grouped = TINY32.moe
-    einsum = dataclasses.replace(grouped, dispatch="einsum",
-                                 capacity_factor=16.0)
-    with jax.default_matmul_precision("highest"):
-        a, aux_a, ra = L.moe_ffn(e, x, grouped)
-        b, aux_b, rb = L.moe_ffn(e, x, einsum)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
-    assert (np.asarray(ra) == np.asarray(rb)).all()
-    assert abs(float(aux_a) - float(aux_b)) < 1e-6
-
-
 @pytest.mark.parametrize("S_,chunk", [(37, 16), (32, 16), (5, 16), (1, 16)])
 def test_bounded_scan_is_the_per_token_recurrence(S_, chunk):
     ks = jax.random.split(jax.random.PRNGKey(S_), 6)
