@@ -14,19 +14,38 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.backend import interpret_mode
-from repro.kernels.flash_attention.flash_attention import (
-    DEFAULT_BK, DEFAULT_BQ, flash_attention_fwd)
+from repro.kernels.flash_attention.flash_attention import flash_attention_fwd
 from repro.kernels.flash_attention.ref import flash_attention_ref
+
+ROWS = 2048     # most query rows (G heads x bq) one grid step multiplies
+BK = 512        # most keys a K/V tile holds; a q tile holds no more
 
 
 def _ceil_to(x, m):
     return (x + m - 1) // m * m
 
 
+def _tiles(S, g):
+    """(bq, bk) for a sequence of S and G query heads a kv head: powers of
+    two times 128, no longer than S needs.  Besides its MXU work a grid step
+    costs a fixed few hundred cycles (the scores' softmax sits between the
+    two dots), so tiles are as large as VMEM keeps them: the q tile of the
+    G heads holds at most ROWS rows, its f32 scores at most ROWS x BK."""
+    top = 128
+    while top < S:
+        top *= 2
+    bq = 128
+    while g * bq * 2 <= ROWS and bq * 2 <= min(BK, top):
+        bq *= 2
+    bk = min(BK, top)
+    while g * bq * bk > ROWS * BK and bk > 128:
+        bk //= 2
+    return bq, bk
+
+
 def _padded_call(q, k, v, causal, window, interpret):
     B, S, H, d = q.shape
-    bq = min(DEFAULT_BQ, _ceil_to(S, 128))
-    bk = min(DEFAULT_BK, _ceil_to(S, 128))
+    bq, bk = _tiles(S, H // k.shape[2])
     Sp = _ceil_to(S, max(bq, bk))
     dp = _ceil_to(d, 128)
 

@@ -89,6 +89,21 @@ def test_flash_forward_backward_compiles(one_chip):
     assert KERNEL in txt
 
 
+@pytest.mark.parametrize("B,S,H_,K_,D_,window", [
+    (32, 1020, 32, 8, 80, 4096),        # danube-decode's prefill
+    (8, 1500, 56, 8, 128, None),        # deepseek-codecomp's prefill
+])
+def test_flash_forward_compiles_cell_prefill(one_chip, B, S, H_, K_, D_,
+                                             window):
+    # the tiles chosen for the cell's shape fit the chip's VMEM
+    q = _sds((B, S, H_, D_), one_chip)
+    kv = _sds((B, S, K_, D_), one_chip)
+    txt = jax.jit(functools.partial(
+        flash_attention, causal=True, window=window, interpret=False)).lower(
+        q, kv, kv).compile().as_text()
+    assert KERNEL in txt
+
+
 def test_decode_attention_compiles_batch_8(one_chip):
     B, W = 8, 2176            # serve cache: prompt 2048 + 128 generated
     Wp = cache_width(W)

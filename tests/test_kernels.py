@@ -26,6 +26,11 @@ KEY = jax.random.PRNGKey(0)
     (2, 200, 4, 1, 80, True, 96),      # GQA + sliding window + padding
     (1, 128, 2, 2, 32, False, None),   # non-causal (whisper encoder)
     (1, 130, 6, 2, 112, True, None),   # ragged seq + kimi head_dim
+    (2, 1020, 8, 2, 80, True, 4096),   # danube's heads 4:1 of 80, its S
+    (1, 1500, 14, 2, 128, True, None),  # deepseek's heads 7:1 of 128, its S
+    (1, 1500, 14, 2, 128, True, 300),  # window: k tiles skipped on the left
+    (2, 600, 14, 2, 128, True, None),  # q tiles wholly in the padding
+    (1, 600, 8, 2, 64, False, None),   # non-causal, padded k tail masked
 ])
 def test_flash_attention_sweep(B, S, H, K, d, causal, win, dtype):
     ks = jax.random.split(KEY, 3)
